@@ -145,7 +145,7 @@ toks_1 = re.findall(r"toks=([\d,]+)", half)
 toks_4 = re.findall(r"toks=([\d,]+)", d4)
 assert toks_1 and toks_4 == toks_1, \
     f"D=4 sharded serve diverged from D=1: {toks_1} vs {toks_4}"
-m = re.search(r"devices: D=4 links=(\d+) link-util=\[([^\]]*)\]", d4)
+m = re.search(r"devices: D=4 links=(\d+) sim-link-util=\[([^\]]*)\]", d4)
 assert m, "D=4 run missing the devices/per-link report line"
 assert int(m.group(1)) >= 4, f"D=4 run used only {m.group(1)} upload links"
 r = re.search(r"rebalances=(\d+)", d4)

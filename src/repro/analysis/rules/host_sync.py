@@ -41,8 +41,9 @@ DECLARED_FENCES: Tuple[Tuple[str, str, str], ...] = (
     ("serving/engine.py", "JaxModelServer._route_iteration",
      "token emission and router-count feedback are the serving loop's "
      "per-step fence"),
-    ("launch/serve.py", "main",
-     "CLI output marshalling happens after the measured region"),
+    ("launch/serve.py", "run",
+     "drain's host-clock time ends when every device computation and "
+     "upload has finished"),
     ("launch/train.py", "main",
      "loss/grad-norm logging at step boundaries is an accepted sync"),
     ("train/loop.py", "train_loop",

@@ -45,8 +45,29 @@ class HWConfig:
 
 
 PAPER_8GPU = HWConfig()
-TPU_V5E = HWConfig(dram_to_dev_gbps=32.0, ssd_to_dram_gbps=6.0,
-                   peak_flops=197e12, hbm_gbps=819.0)
+TPU_V5E = HWConfig(
+    # assumed, not measured: PCIe 4.0 x16 nominal for the host->HBM link
+    dram_to_dev_gbps=32.0,
+    # assumed: the paper testbed's NVMe RAID0 (no SSD tier on the chip host)
+    ssd_to_dram_gbps=6.0,
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    peak_flops=197e12, hbm_gbps=819.0)
+
+# The simulator's preset for each ``jax.Device.device_kind``. CPU keeps the
+# paper testbed (trace mode and tests); an accelerator missing from this
+# table is an error, never a silent default.
+DEVICE_HW = {
+    "cpu": PAPER_8GPU,
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hw_for_device_kind(kind: str) -> HWConfig:
+    try:
+        return DEVICE_HW[kind]
+    except KeyError:
+        raise ValueError(f"no HWConfig preset for device kind {kind!r}; "
+                         f"known kinds: {sorted(DEVICE_HW)}") from None
 
 
 # prefetch priorities live in (0, ~1] (activation ratio × layer decay,

@@ -187,11 +187,8 @@ class ExpertSlotCache:
         self.device = device
         self.bufs = {
             name: jnp.zeros((self.n_slots,) + store.wire_shapes[name],
-                            store.wire_dtypes[name])
+                            store.wire_dtypes[name], device=device)
             for name in store.wire_names}
-        if device is not None:
-            self.bufs = {name: jax.device_put(buf, device)
-                         for name, buf in self.bufs.items()}
         self.slot_of = np.full((store.n_moe, store.n_experts), -1, np.int32)
         self.key_of: List[Optional[Key]] = [None] * self.n_slots
         self._free: List[int] = list(range(self.n_slots))

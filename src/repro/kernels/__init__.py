@@ -7,7 +7,9 @@ spots get TPU-native Pallas kernels (DESIGN.md):
 - flash_decode: single-token flash attention over a long KV cache (GQA)
 - wkv6:         RWKV6 data-dependent-decay recurrence (chunked scan)
 
-Each kernel ships as <name>.py (pl.pallas_call + BlockSpec VMEM tiling),
-with a jit'd dispatch wrapper in ops.py and a pure-jnp oracle in ref.py.
-On this CPU container they are validated with interpret=True.
+Each kernel ships as <name>.py (pl.pallas_call + BlockSpec VMEM tiling)
+with a pure-jnp oracle in ref.py. The CPU tests check them against the
+oracles with interpret=True; tests/test_tpu_compile.py compiles them for a
+described TPU v5e at switch-base-128 widths. None is on the served path
+yet: a caller picks the kernel or the oracle explicitly.
 """

@@ -89,10 +89,11 @@ def moe_ffn(xg, w_gate, w_up, w_down, *, act: str = "swiglu",
 
 def _quant_kernel(refs, *, act: str, bf: int, gated: bool, scaled: bool):
     """Dequantizing variant: weight refs arrive in a narrow wire dtype
-    (fp16/int8) plus optional per-output-channel fp32 scale refs, and are
-    widened to fp32 *inside* the kernel, right before each GEMM — so the
-    wire dtype never touches the math (compute accumulates fp32, like the
-    dense kernel) and VMEM holds the narrow blocks, not widened copies."""
+    (int8, or fp32 for the widened fp16 wire) plus optional
+    per-output-channel fp32 scale refs, and are widened to fp32 *inside*
+    the kernel, right before each GEMM — so the wire dtype never touches
+    the math (compute accumulates fp32, like the dense kernel) and VMEM
+    holds the narrow int8 blocks, not widened copies."""
     it = iter(refs)
     x_ref = next(it)
     wg_ref = next(it) if gated else None
@@ -107,9 +108,9 @@ def _quant_kernel(refs, *, act: str, bf: int, gated: bool, scaled: bool):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    def deq(w_ref, s_ref):                  # (1, a, b) wire + (1, b) scales
+    def deq(w_ref, s_ref):               # (1, a, b) wire + (1, 1, b) scales
         w = w_ref[0].astype(jnp.float32)
-        return w if s_ref is None else w * s_ref[0][None, :]
+        return w if s_ref is None else w * s_ref[0]
 
     x = x_ref[0].astype(jnp.float32)        # (bc, d)
     up = jnp.dot(x, deq(wu_ref, su_ref), preferred_element_type=jnp.float32)
@@ -141,10 +142,21 @@ def moe_ffn_quant(xg, w_gate, w_up, w_down, sg=None, su=None, sd=None, *,
     fp16). Dequantization happens on-device inside the kernel; with fp32
     weights and no scales this *delegates* to :func:`moe_ffn`, so the fp32
     wire path is literally the dense kernel (bit-identity by construction).
+
+    TPU layout: fp16 weights are widened to fp32 before the kernel (the
+    v5e's Mosaic has no f16 vector loads; the widening is exact, so the
+    result equals in-kernel widening), and the scales enter as
+    (E, 1, f)/(E, 1, d) so each block's last two dims are (1, full) — a
+    (1, bf) block of an (E, f) array breaks the (8, 128) tiling rule.
     """
     if su is None and w_up.dtype == xg.dtype:
         return moe_ffn(xg, w_gate, w_up, w_down, act=act, block_c=block_c,
                        block_f=block_f, interpret=interpret)
+
+    def widen(w):
+        return w if w is None or w.dtype != jnp.float16 \
+            else w.astype(jnp.float32)
+    w_gate, w_up, w_down = widen(w_gate), widen(w_up), widen(w_down)
     E, C, d = xg.shape
     f = w_up.shape[2]
     bc = min(block_c, C)
@@ -163,13 +175,13 @@ def moe_ffn_quant(xg, w_gate, w_up, w_down, sg=None, su=None, sd=None, *,
     in_specs += [w_spec, pl.BlockSpec((1, bf, d), lambda e, i, j: (e, j, 0))]
     operands += [w_up, w_down]
     if scaled:
-        f_scale = pl.BlockSpec((1, bf), lambda e, i, j: (e, j))
-        d_scale = pl.BlockSpec((1, d), lambda e, i, j: (e, 0))
+        f_scale = pl.BlockSpec((1, 1, bf), lambda e, i, j: (e, 0, j))
+        d_scale = pl.BlockSpec((1, 1, d), lambda e, i, j: (e, 0, 0))
         if gated:
             in_specs.append(f_scale)
-            operands.append(sg)
+            operands.append(sg.reshape(E, 1, f))
         in_specs += [f_scale, d_scale]
-        operands += [su, sd]
+        operands += [su.reshape(E, 1, f), sd.reshape(E, 1, d)]
 
     kernel = functools.partial(
         lambda *refs, **kw: _quant_kernel(refs, **kw),
@@ -231,7 +243,6 @@ def moe_ffn_sharded(xg, w_gate, w_up, w_down, *, mesh, axis_name="expert",
     :func:`moe_ffn` by construction (no pad, identity exchange, same
     kernel), and D>1 is bit-identical per token row.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     D = int(mesh.shape[axis_name])
@@ -270,8 +281,8 @@ def moe_ffn_sharded(xg, w_gate, w_up, w_down, *, mesh, axis_name="expert",
     w_spec = P(axis_name, None, None)
     operands = (xg,) + ((w_gate,) if gated else ()) + (w_up, w_down)
     in_specs = (x_spec,) + (w_spec,) * (len(operands) - 1)
-    y = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=x_spec,
-                  check_rep=False)(*operands)
+    y = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=x_spec,
+                      check_vma=False)(*operands)
     return y[:, :C] if Cp != C else y
 
 

@@ -6,6 +6,15 @@ touches jax device state — the dry-run must set XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, names):
+    """``jax.make_mesh`` with Auto axes: the launchers' sharding rules are
+    GSPMD propagation hints, and explicit axes (the make_mesh default)
+    would demand an output sharding on every ambiguous op instead."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False, expert: bool = False):
@@ -18,24 +27,23 @@ def make_production_mesh(*, multi_pod: bool = False, expert: bool = False):
     the remaining "model" axis at the same chip count."""
     if multi_pod:
         if expert:
-            return jax.make_mesh((2, 16, 4, 4),
-                                 ("pod", "data", "model", "expert"))
-        return jax.make_mesh((2, 16, 16), ("pod", "data", "model"))
+            return _auto_mesh((2, 16, 4, 4),
+                              ("pod", "data", "model", "expert"))
+        return _auto_mesh((2, 16, 16), ("pod", "data", "model"))
     if expert:
-        return jax.make_mesh((16, 4, 4), ("data", "model", "expert"))
-    return jax.make_mesh((16, 16), ("data", "model"))
+        return _auto_mesh((16, 4, 4), ("data", "model", "expert"))
+    return _auto_mesh((16, 16), ("data", "model"))
 
 
 def make_debug_mesh(*, multi_pod: bool = False, expert: bool = False):
     """Reduced mesh for CI smoke tests (needs only 8/16 host devices)."""
     if multi_pod:
         if expert:
-            return jax.make_mesh((2, 2, 2, 2),
-                                 ("pod", "data", "model", "expert"))
-        return jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+            return _auto_mesh((2, 2, 2, 2), ("pod", "data", "model", "expert"))
+        return _auto_mesh((2, 2, 4), ("pod", "data", "model"))
     if expert:
-        return jax.make_mesh((2, 2, 2), ("data", "model", "expert"))
-    return jax.make_mesh((2, 4), ("data", "model"))
+        return _auto_mesh((2, 2, 2), ("data", "model", "expert"))
+    return _auto_mesh((2, 4), ("data", "model"))
 
 
 def make_expert_mesh(n_devices: int | None = None):

@@ -1,7 +1,8 @@
 """Serving launcher: wires a (possibly sharded) model + the offload engine
-into an open-loop request loop. On this CPU container it runs reduced
-configs end to end; on real hardware the same entry point takes the full
-config + the production mesh.
+into an open-loop request loop. On a CPU it runs ``--reduced`` configs end
+to end; on a TPU the same entry point serves full-width configs
+(``chip_smoke.py`` drives it through :func:`build`, :func:`run` and
+:func:`report`, the three steps of :func:`main`).
 
 Requests arrive per a Poisson process with per-request (ragged) prompt
 lengths and token budgets; the slot-pool ``JaxModelServer`` admits them at
@@ -31,14 +32,15 @@ from __future__ import annotations
 
 import argparse
 import os
-from dataclasses import replace
+import time
+from dataclasses import dataclass, field, replace
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.eam import EAMC
-from repro.core.memsim import PAPER_8GPU
+from repro.core.memsim import hw_for_device_kind
 from repro.core.predictor import LearnedPredictor
 from repro.core.tracer import build_eamc
 from repro.models import Model
@@ -51,7 +53,23 @@ from repro.serving.workload import poisson_arrivals
 from repro.train.data import DataConfig, TokenStream
 
 
-def main(argv=None):
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    left to JAX; otherwise the cache lives at ``<repo>/.jax_cache``. The
+    directory must not move between runs, or nothing is ever found again."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-moe-235b-a22b")
     ap.add_argument("--reduced", action="store_true",
@@ -101,8 +119,8 @@ def main(argv=None):
                          "setup on top of the bandwidth term (0 = ideal)")
     ap.add_argument("--dram-gbps", type=float, default=None,
                     help="DRAM→device link bandwidth in GB/s (the paper's "
-                         "PCIe sweep, Figure 10; default: the PAPER_8GPU "
-                         "preset)")
+                         "PCIe sweep, Figure 10; default: the device "
+                         "kind's simulator preset)")
     ap.add_argument("--gpu-links", type=int, default=1,
                     help="parallel DRAM→device upload links the simulator "
                          "charges transfers against (§7)")
@@ -155,8 +173,47 @@ def main(argv=None):
                          "policy admits interactive < standard < batch, "
                          "with aging so batch never starves")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+@dataclass
+class Served:
+    """One built server with its submitted requests (see :func:`build`)."""
+    args: argparse.Namespace
+    cfg: object
+    model: Model
+    srv: JaxModelServer
+    reqs: list
+    eamc: EAMC
+    eamc_source: str
+    predictor_source: str
+    tenants: tuple
+    tokens: dict = field(default_factory=dict)   # rid -> generated tokens
+    drain_s: float = 0.0                         # host clock, see run()
+
+
+def _build_eamc(args, model, params, dataset):
+    """-> (EAMC, source): warm-restarted, cold, or built offline from a
+    forward pass over the warmup dataset."""
+    if args.eamc_path and os.path.exists(EAMC._resolve_path(args.eamc_path)):
+        eamc = EAMC.load(args.eamc_path)
+        eamc.capacity = max(eamc.capacity, args.eamc_capacity)
+        return eamc, "load"
+    if args.eamc_online:
+        # cold start: no oracle-peek warmup pass — the engine learns the
+        # collection from its own traffic
+        return EAMC(capacity=args.eamc_capacity), "cold"
+    fwd = jax.jit(lambda p, b: model.forward(p, b)[1]["counts"])
+
+    def run_fn(seq):
+        return np.asarray(fwd(params, {"tokens": seq[None]}))[:, 0, :]
+    return build_eamc(run_fn, dataset, capacity=args.eamc_capacity), \
+        "offline"
+
+
+def build(args: argparse.Namespace) -> Served:
+    """Initialize the model, build the EAMC and the server, and submit the
+    open-loop requests. Nothing is served yet (see :func:`run`)."""
     # TenantSpec is rebuilt field-by-field here so every spec knob is
     # constructor-plumbed from launch code (config-drift R5) and the
     # --sla-class override applies uniformly
@@ -173,16 +230,6 @@ def main(argv=None):
                        rps=t.rps)
             for t in load_tenants(args.tenants))
 
-    if args.devices > 1:
-        # must happen before the first jax device use: force enough host
-        # devices for the expert mesh (the dryrun launcher's pattern). A
-        # user-supplied count in XLA_FLAGS wins.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count="
-                f"{args.devices}").strip()
-
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -191,30 +238,16 @@ def main(argv=None):
                          "degenerates to layer streaming (see DESIGN.md §5). "
                          "Pick an MoE arch for this launcher.")
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # jitted, init writes each stacked leaf once; eagerly it holds every
+    # per-layer piece beside the stack, twice the expert set at its peak
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
 
     data = TokenStream(DataConfig(vocab=cfg.vocab,
                                   seq_len=args.prompt_len + 4, batch=1))
-    fwd = jax.jit(lambda p, b: model.forward(p, b)[1]["counts"])
-
-    def run_fn(seq):
-        return np.asarray(fwd(params, {"tokens": seq[None]}))[:, 0, :]
-
     dataset = [b["tokens"][0] for b in data.batches(max(10, args.requests))]
-    eamc_source = "offline"
-    if args.eamc_path and os.path.exists(EAMC._resolve_path(args.eamc_path)):
-        eamc = EAMC.load(args.eamc_path)
-        eamc.capacity = max(eamc.capacity, args.eamc_capacity)
-        eamc_source = "load"
-    elif args.eamc_online:
-        # cold start: no oracle-peek warmup pass — the engine learns the
-        # collection from its own traffic
-        eamc = EAMC(capacity=args.eamc_capacity)
-        eamc_source = "cold"
-    else:
-        eamc = build_eamc(run_fn, dataset, capacity=args.eamc_capacity)
+    eamc, eamc_source = _build_eamc(args, model, params, dataset)
 
-    hw = PAPER_8GPU
+    hw = hw_for_device_kind(jax.devices()[0].device_kind)
     if args.ssd_gbps is not None or args.ssd_iops:
         hw = replace(hw,
                      ssd_to_dram_gbps=(args.ssd_gbps if args.ssd_gbps
@@ -242,6 +275,10 @@ def main(argv=None):
                      tenants=tenants),
         model, params, eamc=eamc,
         cache_len=args.prompt_len + args.max_new)
+    # streamed mode: the host store now holds the experts and the server
+    # only the stripped tree, so dropping this last reference frees the
+    # full tree's HBM — the slot buffers are the only experts on device
+    del params
 
     # learned-predictor warm restart (the --eamc-path pattern): the engine
     # already constructed the brain from the config; persisted model state
@@ -285,26 +322,48 @@ def main(argv=None):
             r.sla_class = args.sla_class
         reqs.append(r)
         srv.submit(r)
+    return Served(args=args, cfg=cfg, model=model, srv=srv, reqs=reqs,
+                  eamc=eamc, eamc_source=eamc_source,
+                  predictor_source=predictor_source, tenants=tenants)
+
+
+def run(served: Served) -> None:
+    """Serve every submitted request to completion. ``drain_s`` is host
+    wall time up to the point where every device computation and upload
+    has finished (compiles included)."""
+    srv = served.srv
+    t0 = time.perf_counter()
     # every jit entry (decode step, each prefill bucket, slot splices) may
     # trace exactly once across the whole run; a steady-state retrace
     # raises RecompileError instead of silently stalling the pipeline
     with recompile_guard(srv, max_traces_per_key=1):
         srv.drain()
-    print(f"guard: zero-recompile ok (keys={len(srv.compile_counts)})")
+    jax.block_until_ready(jax.live_arrays())
+    served.drain_s = time.perf_counter() - t0
+    served.tokens = {r.rid: srv.generated.pop(r.rid) for r in served.reqs}
 
+
+def report(served: Served) -> None:
+    """Print the run report. Latencies tagged ``sim-`` are modelled on the
+    simulator's virtual clock, not measured; ``wall:`` is the host clock."""
+    args, srv, reqs, cfg = served.args, served.srv, served.reqs, served.cfg
+    tenants = served.tenants
+    print(f"guard: zero-recompile ok (keys={len(srv.compile_counts)})")
+    print(f"wall: drain={served.drain_s:.3f}s (host clock, compiles "
+          "included)")
     stats = srv.stats()
     for r in reqs:
-        toks = srv.generated.pop(r.rid)
+        toks = served.tokens[r.rid]
         print(f"req {r.rid}: prompt={r.prompt_len} new={len(toks)} "
-              f"slotwait={r.queue_delay*1e3:.1f}ms "
-              f"e2e={r.latency*1e3:.1f}ms "
-              f"tok-lat={r.per_token_latency*1e3:.2f}ms "
+              f"sim-slotwait={r.queue_delay*1e3:.1f}ms "
+              f"sim-e2e={r.latency*1e3:.1f}ms "
+              f"sim-tok-lat={r.per_token_latency*1e3:.2f}ms "
               f"toks={','.join(str(t) for t in toks)}")
     e2e = np.mean([r.latency for r in reqs])
     print(f"total: {args.requests} requests, policy={args.policy}, "
           f"hit={stats['gpu_hit_ratio']:.3f}, "
-          f"mean-tok-lat={stats['mean_token_latency']*1e3:.2f}ms, "
-          f"mean-e2e={e2e*1e3:.1f}ms, "
+          f"sim-mean-tok-lat={stats['mean_token_latency']*1e3:.2f}ms, "
+          f"sim-mean-e2e={e2e*1e3:.1f}ms, "
           f"compiles={dict(srv.compile_counts)}")
     print(f"tiers: demand dram={stats['demand_from_dram']} "
           f"ssd={stats['demand_from_ssd']} "
@@ -313,10 +372,10 @@ def main(argv=None):
           f"(demand {stats['pcie_demand_bytes']/1e6:.1f}), "
           f"ssd={stats['ssd_bytes']/1e6:.1f}MB "
           f"(demand {stats['ssd_demand_bytes']/1e6:.1f}), "
-          f"miss-cost dram={stats['miss_cost_dram']*1e3:.2f}ms "
+          f"sim-miss-cost dram={stats['miss_cost_dram']*1e3:.2f}ms "
           f"ssd={stats['miss_cost_ssd']*1e3:.2f}ms")
     if srv.slot_runtime is not None:
-        n_moe = len(model.moe_layers)
+        n_moe = len(served.model.moe_layers)
         total = n_moe * cfg.moe.n_experts
         print(f"slots: resident={stats['weight_slots']}/{total} "
               f"hit-ratio={stats['slot_hit_ratio']:.3f} "
@@ -338,18 +397,20 @@ def main(argv=None):
         util = " ".join(f"{l['utilization']:.3f}" for l in links)
         busy = " ".join(f"{l['busy_s']*1e3:.1f}" for l in links)
         print(f"devices: D={args.devices} links={stats['n_gpu_links']} "
-              f"link-util=[{util}] link-busy-ms=[{busy}] "
+              f"sim-link-util=[{util}] sim-link-busy-ms=[{busy}] "
               f"rebalances={stats['placement_rebalances']} "
               f"migrations={stats['placement_migrations']} "
               f"replicated={stats['replicated_experts']}")
     learned = stats["eamc_online_inserts"] + stats["eamc_online_merges"]
-    print(f"eamc: source={eamc_source} entries={stats['eamc_entries']} "
+    print(f"eamc: source={served.eamc_source} "
+          f"entries={stats['eamc_entries']} "
           f"learned={learned} "
           f"(insert={stats['eamc_online_inserts']} "
           f"merge={stats['eamc_online_merges']}) "
           f"recon={stats['eamc_reconstructions']} "
           f"mean-dist={stats['eamc_mean_match_distance']:.3f}")
-    print(f"predictor: kind={stats['predictor']} source={predictor_source} "
+    print(f"predictor: kind={stats['predictor']} "
+          f"source={served.predictor_source} "
           f"seqs={stats.get('predictor_seqs_trained', 0)}")
     if tenants:
         tstats = stats.get("tenants", {})
@@ -364,7 +425,7 @@ def main(argv=None):
                    if rs else 0.0)
             print(f"tenant {t.tenant_id}: sla={t.sla_class} n={len(rs)} "
                   f"hit={ts.get('gpu_hit_ratio', 0.0):.3f} "
-                  f"p99={p99*1e3:.1f}ms "
+                  f"sim-p99={p99*1e3:.1f}ms "
                   f"deferrals={defs.get(t.tenant_id, 0)} "
                   f"slots={ts.get('gpu_slots_owned', 0)}"
                   f"{'/' + str(t.gpu_slot_quota) if t.gpu_slot_quota else ''} "
@@ -375,12 +436,30 @@ def main(argv=None):
         for tid, saved in srv.offload.save_tenant_state().items():
             print(f"tenant {tid}: saved predictor -> {saved}")
     if args.eamc_path:
-        saved = eamc.save(args.eamc_path)
+        saved = served.eamc.save(args.eamc_path)
         print(f"eamc: saved {stats['eamc_entries']} entries -> {saved}")
     if args.predictor_path and args.predictor in ("learned", "hybrid"):
         saved = srv.offload.predictor.save(args.predictor_path)
         print(f"predictor: saved seqs="
               f"{stats.get('predictor_seqs_trained', 0)} -> {saved}")
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.devices > 1:
+        # must happen before the first jax device use: force enough host
+        # devices for the expert mesh on a CPU host (the dryrun launcher's
+        # pattern; accelerator backends ignore it). A user-supplied count
+        # in XLA_FLAGS wins.
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "--xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags + " --xla_force_host_platform_device_count="
+                f"{args.devices}").strip()
+    init_compile_cache()
+    served = build(args)
+    run(served)
+    report(served)
 
 
 if __name__ == "__main__":

@@ -1,10 +1,24 @@
-"""Shared building blocks: norms, activations, RoPE / M-RoPE, FFNs."""
+"""Shared building blocks: embedding lookup, norms, activations, RoPE /
+M-RoPE, FFNs."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.config import ArchConfig
+
+
+def embed_lookup(table, ids):
+    """``table[ids]`` row gather. Under explicit mesh axes a vocab-sharded
+    table leaves the gather's output sharding ambiguous, so it is named:
+    laid out like ``ids`` with the feature dim replicated. Without a mesh,
+    or under Auto axes (GSPMD propagates it), this is the plain gather."""
+    mesh = jax.typeof(table).sharding.mesh
+    if AxisType.Explicit not in mesh.axis_types:
+        return table[ids]
+    spec = P(*jax.typeof(ids).sharding.spec, None)
+    return table.at[ids].get(out_sharding=NamedSharding(mesh, spec))
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,8 @@ from repro.config import ArchConfig, BLOCK_ATTN, BLOCK_MAMBA, BLOCK_RWKV
 from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
 from repro.models import rwkv6 as rwkv_lib
-from repro.models.layers import apply_ffn, apply_norm, init_ffn, init_norm, softcap
+from repro.models.layers import (apply_ffn, apply_norm, embed_lookup,
+                                 init_ffn, init_norm, softcap)
 from repro.models.moe import init_moe, moe_ffn
 
 
@@ -248,7 +249,7 @@ class Model:
         if "embeds" in batch:
             x = batch["embeds"].astype(self.dtype)
         else:
-            x = params["embed"][batch["tokens"]]
+            x = embed_lookup(params["embed"], batch["tokens"])
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model ** 0.5, self.dtype)
         B, S = x.shape[:2]
@@ -679,7 +680,7 @@ class Model:
         if active is not None:
             active = jnp.asarray(active, bool)
         if token_or_embeds.ndim == 1:
-            x = params["embed"][token_or_embeds][:, None]
+            x = embed_lookup(params["embed"], token_or_embeds)[:, None]
         else:
             x = token_or_embeds.astype(self.dtype)
         if cfg.embed_scale:

@@ -53,6 +53,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.core.slot_cache import ExpertSlotCache, HostExpertStore
+from repro.models.layers import embed_lookup
 from repro.models.moe import route
 from repro.serving.guard import bump_trace_count
 
@@ -187,7 +188,7 @@ class SlotStreamRuntime:
 
             def impl(params, tok, pos):
                 self._count("slot_embed")
-                x = params["embed"][tok][:, None]
+                x = embed_lookup(params["embed"], tok)[:, None]
                 if cfg.embed_scale:
                     x = x * jnp.asarray(cfg.d_model ** 0.5, model.dtype)
                 if not cfg.attn.use_rope:
@@ -537,8 +538,11 @@ class ShardedSlotRuntime(SlotStreamRuntime):
         self._rep = NamedSharding(mesh, P())
         self._shard = NamedSharding(mesh, P("expert"))
         # replicate all device-side runtime state over the mesh so every
-        # per-layer jit is one SPMD computation on the same device set
-        self.params = jax.device_put(self.params, self._rep)
+        # per-layer jit is one SPMD computation on the same device set; the
+        # store's reference moves too, or the unreplicated copy would stay
+        # alive on the default device
+        self.params = self.store.stripped_params = jax.device_put(
+            self.params, self._rep)
         self._layer_params = jax.device_put(self._layer_params, self._rep)
 
     def _init_slot_caches(self, n_weight_slots: int, fenced: bool) -> None:

@@ -28,9 +28,11 @@ class TokenStream:
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
         rng = np.random.default_rng(cfg.seed)
-        # per-task Markov transition matrices over a vocab slice
+        # per-task Markov transition matrices over a vocab slice. The
+        # matrices are dense (width², float64), so the slice is capped:
+        # half of a 32k vocab would take 6 GB of host memory
         self._starts, self._trans = [], []
-        width = max(16, cfg.vocab // 2)
+        width = max(16, min(cfg.vocab // 2, 4096))
         for t in range(cfg.n_tasks):
             start = (t * (cfg.vocab - width)) // max(1, cfg.n_tasks - 1) \
                 if cfg.n_tasks > 1 else 0

@@ -123,13 +123,3 @@ def test_wkv6_state_carries_across_chunks():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-5)
 
-
-def test_ops_dispatch_uses_ref_on_cpu():
-    from repro.kernels import ops
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = jax.random.normal(ks[0], (1, 4, 64))
-    k = jax.random.normal(ks[1], (1, 128, 4, 64))
-    v = jax.random.normal(ks[2], (1, 128, 4, 64))
-    y = ops.decode_attention(q, k, v, 128)
-    y_ref = ref.flash_decode_ref(q, k, v, 128)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-6)

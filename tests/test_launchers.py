@@ -10,7 +10,7 @@ def test_serve_launcher_reduced(capsys):
     serve_mod.main(["--arch", "qwen3-moe-235b-a22b", "--reduced",
                     "--requests", "2", "--prompt-len", "6", "--max-new", "3"])
     out = capsys.readouterr().out
-    assert "hit=" in out and "tok-lat=" in out
+    assert "hit=" in out and "sim-tok-lat=" in out
 
 
 def test_train_launcher_reduced(capsys, tmp_path):

@@ -87,3 +87,23 @@ def test_embed_vocab_sharding():
         P("model", None)
     assert param_spec("embed", (51865, 768), m, stacked=False) == \
         P(None, None)  # 51865 % 16 != 0 → replicate
+
+
+@pytest.mark.parametrize("axis_type", ["Explicit", "Auto"])
+def test_embed_lookup_vocab_sharded_table(axis_type):
+    """The embedding gather on a vocab-sharded table: under explicit axes
+    JAX refuses the plain ``table[ids]`` (ambiguous output sharding), so
+    the lookup names it. Either way the rows are exactly ``table[ids]``."""
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, NamedSharding
+    from repro.models.layers import embed_lookup
+    kind = getattr(AxisType, axis_type)
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(kind,) * 2)
+    table = jax.device_put(jnp.arange(64 * 16, dtype=jnp.float32)
+                           .reshape(64, 16), NamedSharding(mesh, P("model")))
+    ids = jax.device_put(jnp.array([[1, 5, 63]], jnp.int32),
+                         NamedSharding(mesh, P("data")))
+    with jax.set_mesh(mesh):
+        rows = jax.jit(embed_lookup)(table, ids)
+    assert np.array_equal(np.asarray(rows),
+                          np.asarray(table)[np.asarray(ids)])
