@@ -34,7 +34,9 @@ from __future__ import annotations
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import quant
 
@@ -69,6 +71,12 @@ def strip_expert_weights(params):
             if "moe" in b else b
             for b in params["blocks"]]
     return out
+
+
+def slot_splice(buf, w, s):
+    """``buf`` with row ``s`` replaced by ``w`` (the program
+    ``jit_slot_splice``, donated: the slot buffer is updated in place)."""
+    return jax.lax.dynamic_update_slice_in_dim(buf, w[None], s, 0)
 
 
 class HostExpertStore:
@@ -175,7 +183,6 @@ class ExpertSlotCache:
 
     def __init__(self, store: HostExpertStore, n_slots: int, *,
                  fenced: bool = False, device=None):
-        import jax
         import jax.numpy as jnp
         self._jax, self._jnp = jax, jnp
         self.store = store
@@ -197,10 +204,7 @@ class ExpertSlotCache:
         # overwrites its pending rows, so commit never double-writes.
         self._staged: Dict[int, Dict[str, object]] = {}
         self._splice_fns = {
-            name: jax.jit(
-                lambda buf, w, s: jax.lax.dynamic_update_slice_in_dim(
-                    buf, w[None], s, 0),
-                donate_argnums=(0,))
+            name: jax.jit(slot_splice, donate_argnums=(0,))
             for name in store.wire_names}
         # stats (expert-granularity; the serving engine derives per-token
         # rates from these plus its token counters)
@@ -248,11 +252,12 @@ class ExpertSlotCache:
         kernel still executing against the previous ``bufs`` value is
         untouched (functional no-alias guarantee)."""
         if self._staged:
-            for slot, rows in self._staged.items():
-                for name, arr in rows.items():
-                    self.bufs[name] = self._splice_fns[name](
-                        self.bufs[name], arr, slot)
-            self._staged.clear()
+            with TraceAnnotation("slots.commit", rows=len(self._staged)):
+                for slot, rows in self._staged.items():
+                    for name, arr in rows.items():
+                        self.bufs[name] = self._splice_fns[name](
+                            self.bufs[name], arr, slot)
+                self._staged.clear()
         return self.bufs
 
     def evict(self, key: Key) -> None:
@@ -312,28 +317,32 @@ class ExpertSlotCache:
         prefetch uploads the demand copy queued behind, like a demand read
         behind issued copies on a real link."""
         missing = [k for k in keys if k not in self]
-        self.hits += len(keys) - len(missing)
-        self.misses += len(missing)
-        if not missing:
-            return 0
-        t0 = time.perf_counter()
-        protected = frozenset(keys)
-        for key in missing:
-            if not self._free:
-                victim = victim_fn(self.resident, protected) \
-                    if victim_fn else next(
-                        k for k in self.key_of if k not in protected)
-                if victim is None or victim in protected:
-                    raise RuntimeError(
-                        f"expert slot cache too small: {self.n_slots} slots "
-                        f"cannot hold one layer's {len(keys)} routed experts")
-                self.evict(victim)
-            self._stage(key)
-            self.demand_uploads += 1
-        if self.fenced:
-            self.fence()
-        self.demand_stall_s += time.perf_counter() - t0
-        return len(missing)
+        layer = keys[0][0] if keys else -1
+        with TraceAnnotation("slots.ensure", layer=layer,
+                             misses=len(missing)):
+            self.hits += len(keys) - len(missing)
+            self.misses += len(missing)
+            if not missing:
+                return 0
+            t0 = time.perf_counter()
+            protected = frozenset(keys)
+            for key in missing:
+                if not self._free:
+                    victim = victim_fn(self.resident, protected) \
+                        if victim_fn else next(
+                            k for k in self.key_of if k not in protected)
+                    if victim is None or victim in protected:
+                        raise RuntimeError(
+                            f"expert slot cache too small: {self.n_slots} "
+                            f"slots cannot hold one layer's {len(keys)} "
+                            "routed experts")
+                    self.evict(victim)
+                self._stage(key)
+                self.demand_uploads += 1
+            if self.fenced:
+                self.fence()
+            self.demand_stall_s += time.perf_counter() - t0
+            return len(missing)
 
     def stats(self) -> dict:
         return {

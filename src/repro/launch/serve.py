@@ -377,6 +377,9 @@ def report(served: Served) -> None:
     if srv.slot_runtime is not None:
         n_moe = len(served.model.moe_layers)
         total = n_moe * cfg.moe.n_experts
+        # overlapped uploads block the host only to issue the device_put;
+        # the fenced schedule waits for each demand upload to land
+        demand = "demand-stall" if args.fenced_uploads else "demand-issue"
         print(f"slots: resident={stats['weight_slots']}/{total} "
               f"hit-ratio={stats['slot_hit_ratio']:.3f} "
               f"hits={stats['slot_hits']} misses={stats['slot_misses']} "
@@ -384,7 +387,7 @@ def report(served: Served) -> None:
               f"prefetch-uploads={stats['prefetch_uploads']} "
               f"evictions={stats['slot_evictions']} "
               f"uploaded={stats['upload_bytes']/1e6:.1f}MB "
-              f"demand-stall={stats['demand_stall_s']*1e3:.1f}ms "
+              f"{demand}={stats['demand_stall_s']*1e3:.1f}ms "
               f"({stats['demand_stall_per_token_s']*1e3:.2f}ms/token) "
               f"wire={stats['transfer_dtype']} "
               f"({stats['wire_expert_bytes']}B/expert, "

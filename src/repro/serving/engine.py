@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import ArchConfig
 from repro.core.eam import EAMC
@@ -225,29 +226,33 @@ class StepEngine:
             return not scheduler.done()
 
         reqs = list(self._running)     # admission order = batch columns
-        tokens, ctxs = [], []
-        for r in reqs:
-            if r.state == PREFILL:
-                tokens.append(r.prompt_len)
-                ctxs.append(r.prompt_len)
-            else:
-                tokens.append(1)
-                ctxs.append(r.prompt_len + r.n_generated)
-        counts = self._route_iteration(reqs, tokens)
-        self._execute_iteration(reqs, counts, tokens, ctxs)
+        n_prefill = sum(r.state == PREFILL for r in reqs)
+        with TraceAnnotation("engine.step", prefill=n_prefill,
+                             decode=len(reqs) - n_prefill):
+            tokens, ctxs = [], []
+            for r in reqs:
+                if r.state == PREFILL:
+                    tokens.append(r.prompt_len)
+                    ctxs.append(r.prompt_len)
+                else:
+                    tokens.append(1)
+                    ctxs.append(r.prompt_len + r.n_generated)
+            counts = self._route_iteration(reqs, tokens)
+            self._execute_iteration(reqs, counts, tokens, ctxs)
 
-        now = sim.clock
-        for b, r in enumerate(reqs):
-            self.tracer.record(r.rid, counts[:, b, :])
-            if r.state == PREFILL:
-                r.t_first = now            # prefill emitted the first token
-                r.state = DECODE
-            r.n_generated += 1
-            if r.n_generated >= r.max_new_tokens:
-                r.t_done = now
-                r.state = DONE
-                self._retire(r)
-                scheduler.on_finish(r.rid)
+            now = sim.clock
+            with TraceAnnotation("engine.policy"):
+                for b, r in enumerate(reqs):
+                    self.tracer.record(r.rid, counts[:, b, :])
+                    if r.state == PREFILL:
+                        r.t_first = now    # prefill emitted the first token
+                        r.state = DECODE
+                    r.n_generated += 1
+                    if r.n_generated >= r.max_new_tokens:
+                        r.t_done = now
+                        r.state = DONE
+                        self._retire(r)
+                        scheduler.on_finish(r.rid)
         self._running = [r for r in self._running if r.state != DONE]
         return True
 
@@ -270,33 +275,38 @@ class StepEngine:
         are accounted separately: each request contributes its own (tokens,
         context) pair to the roofline instead of the batch being lumped
         under the maximum context."""
-        sim = self.offload.sim
-        t0 = sim.clock
-        token_ctx = list(zip(tokens, ctxs))
-        rids = [r.rid for r in reqs]
-        # dense layers run between MoE layers; amortize their compute evenly
-        # across MoE layer boundaries to keep the event loop per-MoE-layer
-        dense_t = sum(
-            layer_time_mixed(c, self.cfg.hw, token_ctx)
-            for i, c in self._costs.items()
-            if not self.cfg.arch.is_moe_layer(i))
-        slices = max(1, self.n_moe)
-        for li, layer_idx in enumerate(self.moe_layers):
-            sim.advance(dense_t / slices)
-            comp = layer_time_mixed(self._costs[layer_idx], self.cfg.hw,
-                                    token_ctx, float(counts[li].sum()))
-            self.offload.on_layer(li, counts[li], comp, rids=rids)
-        if not self.n_moe:
-            sim.advance(dense_t)
-        lat = sim.clock - t0
-        n_prefill = sum(n for n, r in zip(tokens, reqs) if r.state == PREFILL)
-        n_decode = sum(n for n, r in zip(tokens, reqs) if r.state != PREFILL)
-        self.prefill_tokens += n_prefill
-        self.decode_tokens += n_decode
-        self.token_latencies.append(lat)
-        self.iter_log.append({"t": sim.clock, "n_tokens": sum(tokens),
-                              "n_prefill": n_prefill, "n_decode": n_decode,
-                              "batch": len(reqs), "lat": lat})
+        with TraceAnnotation("engine.policy"):
+            sim = self.offload.sim
+            t0 = sim.clock
+            token_ctx = list(zip(tokens, ctxs))
+            rids = [r.rid for r in reqs]
+            # dense layers run between MoE layers; amortize their compute
+            # evenly across MoE layer boundaries to keep the event loop
+            # per-MoE-layer
+            dense_t = sum(
+                layer_time_mixed(c, self.cfg.hw, token_ctx)
+                for i, c in self._costs.items()
+                if not self.cfg.arch.is_moe_layer(i))
+            slices = max(1, self.n_moe)
+            for li, layer_idx in enumerate(self.moe_layers):
+                sim.advance(dense_t / slices)
+                comp = layer_time_mixed(self._costs[layer_idx], self.cfg.hw,
+                                        token_ctx, float(counts[li].sum()))
+                self.offload.on_layer(li, counts[li], comp, rids=rids)
+            if not self.n_moe:
+                sim.advance(dense_t)
+            lat = sim.clock - t0
+            n_prefill = sum(n for n, r in zip(tokens, reqs)
+                            if r.state == PREFILL)
+            n_decode = sum(n for n, r in zip(tokens, reqs)
+                           if r.state != PREFILL)
+            self.prefill_tokens += n_prefill
+            self.decode_tokens += n_decode
+            self.token_latencies.append(lat)
+            self.iter_log.append({"t": sim.clock, "n_tokens": sum(tokens),
+                                  "n_prefill": n_prefill,
+                                  "n_decode": n_decode,
+                                  "batch": len(reqs), "lat": lat})
 
     # -- batch run (offline replay drivers) -----------------------------------
     def _scheduler_cfg(self) -> SchedulerConfig:
@@ -603,7 +613,7 @@ class JaxModelServer(StepEngine):
             import jax.numpy as jnp
             model = self.model
 
-            def _impl(params, cache, tok, active):
+            def decode_step(params, cache, tok, active):
                 self._count("decode_step")   # runs at trace time only
                 logits, cache, aux = model.serve_step(params, cache, tok,
                                                       active=active)
@@ -612,7 +622,7 @@ class JaxModelServer(StepEngine):
             # the pool cache is rebound to the output every call — donate it
             # so XLA updates it in place instead of copying the whole
             # n_slots x cache_len KV/recurrent state per generated token
-            self._step_fn = jax.jit(_impl, donate_argnums=(1,))
+            self._step_fn = jax.jit(decode_step, donate_argnums=(1,))
         return self._step_fn
 
     def _get_prefill_fn(self, P: int):
@@ -622,7 +632,7 @@ class JaxModelServer(StepEngine):
             import jax.numpy as jnp
             model, cache_len = self.model, self.cache_len
 
-            def _impl(params, pool, toks, true_len, slot):
+            def prefill(params, pool, toks, true_len, slot):
                 self._count(("prefill", P))
                 one = model.init_cache(1, cache_len)
                 logits, one, aux = model.prefill(params, {"tokens": toks},
@@ -630,7 +640,7 @@ class JaxModelServer(StepEngine):
                 pool = model.write_slot(pool, one, slot)
                 return jnp.argmax(logits[0], -1), pool, aux["counts"][:, 0, :]
 
-            fn = self._prefill_fns[P] = jax.jit(_impl, donate_argnums=(1,))
+            fn = self._prefill_fns[P] = jax.jit(prefill, donate_argnums=(1,))
         return fn
 
     # -- routing: prefill joiners into free slots, one pool decode step --------
@@ -660,7 +670,8 @@ class JaxModelServer(StepEngine):
             padded = np.zeros(P, np.int32)
             padded[:S] = np.asarray(r.prompt, np.int32)
             if self.slot_runtime is not None:
-                tok0, cnts = self.slot_runtime.prefill(padded, S, slot)
+                tok0, cnts = self.slot_runtime.prefill(padded, S, slot,
+                                                       rid=r.rid)
             else:
                 tok0, self._cache, cnts = self._get_prefill_fn(P)(
                     self.params, self._cache, jnp.asarray(padded[None]),
@@ -711,7 +722,6 @@ class JaxModelServer(StepEngine):
             tot = rs["slot_hits"] + rs["slot_misses"]
             s["slot_hit_ratio"] = rs["slot_hits"] / tot if tot else 1.0
             toks = max(1, self.prefill_tokens + self.decode_tokens)
-            s["demand_uploads_per_token"] = rs["demand_uploads"] / toks
             s["demand_stall_per_token_s"] = rs["demand_stall_s"] / toks
         return s
 

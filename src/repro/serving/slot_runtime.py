@@ -44,13 +44,22 @@ expert weight it was uploaded from.
 Compile accounting: every jitted piece counts its traces into the server's
 ``compile_counts`` under ``("slot_*", …)`` keys; per distinct layer
 signature there is one compile, not one per layer instance, so warmup cost
-is O(period), like the fused scan.
+is O(period), like the fused scan. Each jitted function is named after its
+key, so a device trace shows ``jit_slot_decode_post`` and not ``jit_impl``.
+
+Spans (``jax.profiler.TraceAnnotation``, free unless a profiler runs):
+``runtime.decode`` / ``runtime.prefill`` around one layer walk, the host's
+reads of the router's top-k, ``post``'s counts and the token as
+``runtime.read.route`` / ``.counts`` / ``.token`` (metadata ``layer``),
+``runtime.sync`` around the residency sync and ``runtime.stage`` around a
+planned layer's uploads.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.slot_cache import ExpertSlotCache, HostExpertStore
 from repro.models.layers import embed_lookup
@@ -131,19 +140,20 @@ class SlotStreamRuntime:
         walk stages layer ``li+1``'s plan while layer ``li``'s ``post``
         computes (:meth:`_stage_plan`). Fenced mode stages everything at
         the boundary, like PR 5."""
-        if self.fenced:
-            return self.slot_cache.sync(target_keys)
-        sc = self.slot_cache
-        target = set(target_keys)
-        for key in sc.resident:
-            if key not in target:
-                sc.evict(key)
-        plan: Dict[int, List] = {}
-        for key in sorted(target):
-            if key not in sc:
-                plan.setdefault(key[0], []).append(key)
-        self._upload_plan = plan
-        return self._stage_plan(0)
+        with TraceAnnotation("runtime.sync"):
+            if self.fenced:
+                return self.slot_cache.sync(target_keys)
+            sc = self.slot_cache
+            target = set(target_keys)
+            for key in sc.resident:
+                if key not in target:
+                    sc.evict(key)
+            plan: Dict[int, List] = {}
+            for key in sorted(target):
+                if key not in sc:
+                    plan.setdefault(key[0], []).append(key)
+            self._upload_plan = plan
+            return self._stage_plan(0)
 
     def _stage_plan(self, li: int) -> int:
         """Stage the planned prefetch-class uploads for MoE layer ``li``
@@ -151,7 +161,8 @@ class SlotStreamRuntime:
         keys = self._upload_plan.pop(li, None)
         if not keys:
             return 0
-        return self.slot_cache.prefetch(keys)
+        with TraceAnnotation("runtime.stage", layer=li, keys=len(keys)):
+            return self.slot_cache.prefetch(keys)
 
     def flush_pending(self) -> None:
         """Stage any still-planned uploads and commit the staging set —
@@ -186,7 +197,7 @@ class SlotStreamRuntime:
             jax, jnp = self._jax, self._jnp
             model, cfg = self.model, self.cfg
 
-            def impl(params, tok, pos):
+            def slot_embed(params, tok, pos):
                 self._count("slot_embed")
                 x = embed_lookup(params["embed"], tok)[:, None]
                 if cfg.embed_scale:
@@ -194,7 +205,7 @@ class SlotStreamRuntime:
                 if not cfg.attn.use_rope:
                     x = x + params["pos_embed"][pos][:, None]
                 return x
-            return jax.jit(impl)
+            return jax.jit(slot_embed)
         return self._fn("slot_embed", build)
 
     def _decode_layer(self, desc):
@@ -203,7 +214,7 @@ class SlotStreamRuntime:
         def build():
             model = self.model
 
-            def impl(p, bc, x, pos, active):
+            def slot_decode(p, bc, x, pos, active):
                 self._count(key)
                 x_out, bc, _ = model._decode_block(p, desc, dict(bc), x, pos,
                                                    0, active=active)
@@ -211,7 +222,7 @@ class SlotStreamRuntime:
             # the pool cache is rebound to the output every call — donate
             # it (as the fused step does) so XLA updates the n_slots ×
             # cache_len state in place instead of copying it per token
-            return self._jax.jit(impl, donate_argnums=(1,))
+            return self._jax.jit(slot_decode, donate_argnums=(1,))
         return self._fn(key, build)
 
     def _decode_pre(self, desc):
@@ -220,14 +231,14 @@ class SlotStreamRuntime:
         def build():
             model, cfg = self.model, self.cfg
 
-            def impl(p, bc, x, pos, active):
+            def slot_decode_pre(p, bc, x, pos, active):
                 self._count(key)
                 x_mid, h2, bc = model._decode_block_pre(
                     p, desc, dict(bc), x, pos, 0, active=active)
                 B, S, d = h2.shape
                 gates, idx, _ = route(p["moe"], cfg.moe, h2.reshape(B * S, d))
                 return x_mid, h2, bc, gates, idx
-            return self._jax.jit(impl, donate_argnums=(1,))
+            return self._jax.jit(slot_decode_pre, donate_argnums=(1,))
         return self._fn(key, build)
 
     def _decode_post(self, desc):
@@ -236,14 +247,15 @@ class SlotStreamRuntime:
         def build():
             model = self.model
 
-            def impl(p, bufs, row, bc, x_mid, h2, gates, idx, active):
+            def slot_decode_post(p, bufs, row, bc, x_mid, h2, gates, idx,
+                                 active):
                 self._count(key)
                 x_out, bc, counts = model._decode_block_post(
                     p, desc, dict(bc), x_mid, h2, active=active,
                     routing=(gates, idx), slot_weights=bufs, slot_ids=row)
                 counts = counts * active.astype(counts.dtype)[:, None]
                 return x_out, bc, counts
-            return self._jax.jit(impl, donate_argnums=(3,))
+            return self._jax.jit(slot_decode_post, donate_argnums=(3,))
         return self._fn(key, build)
 
     def _decode_tail(self):
@@ -251,12 +263,12 @@ class SlotStreamRuntime:
             from repro.models.layers import apply_norm
             jax, jnp, model = self._jax, self._jnp, self.model
 
-            def impl(params, x):
+            def slot_tail(params, x):
                 self._count("slot_tail")
                 x_last = apply_norm(params["final_norm"], x)
                 logits = model._logits(params, x_last)[:, 0]
                 return jnp.argmax(logits, axis=-1)
-            return jax.jit(impl)
+            return jax.jit(slot_tail)
         return self._fn("slot_tail", build)
 
     def _run_decode_post(self, desc, li, p, bc, x_mid, h2, gates, idx,
@@ -277,34 +289,39 @@ class SlotStreamRuntime:
         """One pooled decode step. Returns (new tokens (B,) np, counts
         (n_moe, B, E) np — inactive rows zeroed, like the fused step)."""
         jnp = self._jnp
-        tok = jnp.asarray(tok_np)
-        pos = jnp.asarray(self.pos)
-        active = jnp.asarray(active_np, bool)
-        x = self._decode_embed()(self.params, tok, pos)
-        counts_rows = []
-        for i, desc in enumerate(self.model.descs):
-            p, bc = self._layer_params[i], self.layer_caches[i]
-            if self._is_moe(i):
-                x_mid, h2, bc, gates, idx = self._decode_pre(desc)(
-                    p, bc, x, pos, active)
-                li = self._moe_li[i]
-                idx_np = np.asarray(idx)              # (B·1, k) — sync point
-                rows = np.asarray(active_np, bool)
-                used = (np.unique(idx_np[rows]) if rows.any()
-                        else np.empty(0, np.int64))
-                self._ensure(li, used)
-                x, bc, cnts = self._run_decode_post(
-                    desc, li, p, bc, x_mid, h2, gates, idx, active)
-                # double-buffered overlap: issue the next MoE layer's
-                # planned uploads while this post computes
-                self._stage_plan(li + 1)
-                counts_rows.append(np.asarray(cnts))
-            else:
-                x, bc = self._decode_layer(desc)(p, bc, x, pos, active)
-            self.layer_caches[i] = bc
-        tok_new = np.asarray(self._decode_tail()(self.params, x))
-        self.pos = self.pos + np.asarray(active_np, np.int32)
-        return tok_new, np.stack(counts_rows)
+        with TraceAnnotation("runtime.decode"):
+            tok = jnp.asarray(tok_np)
+            pos = jnp.asarray(self.pos)
+            active = jnp.asarray(active_np, bool)
+            x = self._decode_embed()(self.params, tok, pos)
+            counts_rows = []
+            for i, desc in enumerate(self.model.descs):
+                p, bc = self._layer_params[i], self.layer_caches[i]
+                if self._is_moe(i):
+                    x_mid, h2, bc, gates, idx = self._decode_pre(desc)(
+                        p, bc, x, pos, active)
+                    li = self._moe_li[i]
+                    with TraceAnnotation("runtime.read.route", layer=li):
+                        idx_np = np.asarray(idx)      # (B·1, k) — sync point
+                    rows = np.asarray(active_np, bool)
+                    used = (np.unique(idx_np[rows]) if rows.any()
+                            else np.empty(0, np.int64))
+                    self._ensure(li, used)
+                    x, bc, cnts = self._run_decode_post(
+                        desc, li, p, bc, x_mid, h2, gates, idx, active)
+                    # double-buffered overlap: issue the next MoE layer's
+                    # planned uploads while this post computes
+                    self._stage_plan(li + 1)
+                    with TraceAnnotation("runtime.read.counts", layer=li):
+                        counts_rows.append(np.asarray(cnts))
+                else:
+                    x, bc = self._decode_layer(desc)(p, bc, x, pos, active)
+                self.layer_caches[i] = bc
+            tok_dev = self._decode_tail()(self.params, x)
+            with TraceAnnotation("runtime.read.token"):
+                tok_new = np.asarray(tok_dev)
+            self.pos = self.pos + np.asarray(active_np, np.int32)
+            return tok_new, np.stack(counts_rows)
 
     # -- prefill -------------------------------------------------------------
     def _prefill_embed(self, P):
@@ -313,10 +330,10 @@ class SlotStreamRuntime:
         def build():
             model = self.model
 
-            def impl(params, toks):
+            def slot_prefill_embed(params, toks):
                 self._count(key)
                 return model._embed(params, {"tokens": toks})
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_prefill_embed)
         return self._fn(key, build)
 
     def _prefill_layer(self, desc, P):
@@ -326,7 +343,7 @@ class SlotStreamRuntime:
             from repro.config import BLOCK_RWKV
             model, cache_len = self.model, self.cache_len
 
-            def impl(p, x, positions, true_len):
+            def slot_prefill_layer(p, x, positions, true_len):
                 self._count(key)
                 S = x.shape[1]
                 token_mask = (self._jnp.arange(S)[None, :]
@@ -340,7 +357,7 @@ class SlotStreamRuntime:
                 if desc.kind == BLOCK_RWKV:
                     bc["cm"] = aux2["rwkv_cm"].astype(bc["cm"].dtype)
                 return x_out, bc
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_prefill_layer)
         return self._fn(key, build)
 
     def _prefill_pre(self, desc, P):
@@ -349,7 +366,7 @@ class SlotStreamRuntime:
         def build():
             model, cfg, cache_len = self.model, self.cfg, self.cache_len
 
-            def impl(p, x, positions):
+            def slot_prefill_pre(p, x, positions):
                 self._count(key)
                 x_mid, h2, aux = model._apply_block_pre(p, desc, x, positions)
                 bc = model._block_cache(desc, 1, cache_len, 0)
@@ -357,7 +374,7 @@ class SlotStreamRuntime:
                 B, S, d = h2.shape
                 gates, idx, _ = route(p["moe"], cfg.moe, h2.reshape(B * S, d))
                 return x_mid, h2, bc, gates, idx
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_prefill_pre)
         return self._fn(key, build)
 
     def _prefill_post(self, desc, P):
@@ -366,7 +383,8 @@ class SlotStreamRuntime:
         def build():
             model = self.model
 
-            def impl(p, bufs, row, x_mid, h2, gates, idx, true_len):
+            def slot_prefill_post(p, bufs, row, x_mid, h2, gates, idx,
+                                  true_len):
                 self._count(key)
                 S = h2.shape[1]
                 token_mask = (self._jnp.arange(S)[None, :]
@@ -376,7 +394,7 @@ class SlotStreamRuntime:
                     token_mask=token_mask, routing=(gates, idx),
                     slot_weights=bufs, slot_ids=row)
                 return x_out, aux["counts"]
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_prefill_post)
         return self._fn(key, build)
 
     def _prefill_tail(self, P):
@@ -386,14 +404,14 @@ class SlotStreamRuntime:
             from repro.models.layers import apply_norm
             jax, jnp, model = self._jax, self._jnp, self.model
 
-            def impl(params, x, true_len):
+            def slot_prefill_tail(params, x, true_len):
                 self._count(key)
                 x_last = jnp.take_along_axis(
                     x, (true_len - 1)[:, None, None], axis=1)
                 x_last = apply_norm(params["final_norm"], x_last)
                 logits = model._logits(params, x_last)[:, 0]
                 return jnp.argmax(logits, axis=-1)
-            return jax.jit(impl)
+            return jax.jit(slot_prefill_tail)
         return self._fn(key, build)
 
     def _write_slot(self, desc):
@@ -402,12 +420,12 @@ class SlotStreamRuntime:
         def build():
             jax = self._jax
 
-            def impl(pool_bc, one_bc, slot):
+            def slot_write(pool_bc, one_bc, slot):
                 self._count(key)
                 return jax.tree.map(
                     lambda pb, ob: jax.lax.dynamic_update_slice_in_dim(
                         pb, ob.astype(pb.dtype), slot, 0), pool_bc, one_bc)
-            return jax.jit(impl, donate_argnums=(0,))
+            return jax.jit(slot_write, donate_argnums=(0,))
         return self._fn(key, build)
 
     def _run_prefill_post(self, desc, P, li, p, x_mid, h2, gates, idx, tl):
@@ -417,37 +435,44 @@ class SlotStreamRuntime:
         return self._prefill_post(desc, P)(p, bufs, row, x_mid, h2, gates,
                                            idx, tl)
 
-    def prefill(self, padded_prompt: np.ndarray, true_len: int, slot: int):
+    def prefill(self, padded_prompt: np.ndarray, true_len: int, slot: int,
+                rid: int = -1):
         """Stream one right-padded B=1 prompt through the stack and land
         its per-layer caches in pool row ``slot``. Returns (first generated
-        token, counts (n_moe, E) np — pad tokens excluded)."""
+        token, counts (n_moe, E) np — pad tokens excluded). ``rid`` only
+        labels the ``runtime.prefill`` span."""
         jnp = self._jnp
-        P = len(padded_prompt)
-        toks = jnp.asarray(np.asarray(padded_prompt, np.int32)[None])
-        tl = jnp.asarray([true_len], jnp.int32)
-        slot_dev = jnp.asarray(slot, jnp.int32)
-        x, positions = self._prefill_embed(P)(self.params, toks)
-        counts_rows = []
-        for i, desc in enumerate(self.model.descs):
-            p = self._layer_params[i]
-            if self._is_moe(i):
-                x_mid, h2, bc_one, gates, idx = self._prefill_pre(desc, P)(
-                    p, x, positions)
-                li = self._moe_li[i]
-                idx_np = np.asarray(idx)[:true_len]   # real tokens only
-                self._ensure(li, np.unique(idx_np))
-                x, cnts = self._run_prefill_post(
-                    desc, P, li, p, x_mid, h2, gates, idx, tl)
-                self._stage_plan(li + 1)
-                counts_rows.append(np.asarray(cnts)[0])
-            else:
-                x, bc_one = self._prefill_layer(desc, P)(p, x, positions, tl)
-            self.layer_caches[i] = self._write_slot(desc)(
-                self.layer_caches[i], bc_one, slot_dev)
-        tok0 = int(np.asarray(
-            self._prefill_tail(P)(self.params, x, tl))[0])
-        self.pos[slot] = true_len
-        return tok0, np.stack(counts_rows)
+        with TraceAnnotation("runtime.prefill", rid=rid, slot=slot):
+            P = len(padded_prompt)
+            toks = jnp.asarray(np.asarray(padded_prompt, np.int32)[None])
+            tl = jnp.asarray([true_len], jnp.int32)
+            slot_dev = jnp.asarray(slot, jnp.int32)
+            x, positions = self._prefill_embed(P)(self.params, toks)
+            counts_rows = []
+            for i, desc in enumerate(self.model.descs):
+                p = self._layer_params[i]
+                if self._is_moe(i):
+                    x_mid, h2, bc_one, gates, idx = self._prefill_pre(
+                        desc, P)(p, x, positions)
+                    li = self._moe_li[i]
+                    with TraceAnnotation("runtime.read.route", layer=li):
+                        idx_np = np.asarray(idx)[:true_len]   # real tokens
+                    self._ensure(li, np.unique(idx_np))
+                    x, cnts = self._run_prefill_post(
+                        desc, P, li, p, x_mid, h2, gates, idx, tl)
+                    self._stage_plan(li + 1)
+                    with TraceAnnotation("runtime.read.counts", layer=li):
+                        counts_rows.append(np.asarray(cnts)[0])
+                else:
+                    x, bc_one = self._prefill_layer(desc, P)(
+                        p, x, positions, tl)
+                self.layer_caches[i] = self._write_slot(desc)(
+                    self.layer_caches[i], bc_one, slot_dev)
+            tok_dev = self._prefill_tail(P)(self.params, x, tl)
+            with TraceAnnotation("runtime.read.token"):
+                tok0 = int(np.asarray(tok_dev)[0])
+            self.pos[slot] = true_len
+            return tok0, np.stack(counts_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -580,27 +605,30 @@ class ShardedSlotRuntime(SlotStreamRuntime):
         return out
 
     def sync_residency(self, target_keys) -> int:
-        targets = self._partition_targets(target_keys)
-        if self.fenced:
-            return sum(c.sync(t)
-                       for c, t in zip(self.slot_caches, targets))
-        plan: Dict[int, List] = {}
-        for dev, (cache, tgt) in enumerate(zip(self.slot_caches, targets)):
-            for key in cache.resident:
-                if key not in tgt:
-                    cache.evict(key)
-            for key in sorted(tgt):
-                if key not in cache:
-                    plan.setdefault(key[0], []).append((dev, key))
-        self._upload_plan = plan
-        return self._stage_plan(0)
+        with TraceAnnotation("runtime.sync"):
+            targets = self._partition_targets(target_keys)
+            if self.fenced:
+                return sum(c.sync(t)
+                           for c, t in zip(self.slot_caches, targets))
+            plan: Dict[int, List] = {}
+            for dev, (cache, tgt) in enumerate(zip(self.slot_caches,
+                                                   targets)):
+                for key in cache.resident:
+                    if key not in tgt:
+                        cache.evict(key)
+                for key in sorted(tgt):
+                    if key not in cache:
+                        plan.setdefault(key[0], []).append((dev, key))
+            self._upload_plan = plan
+            return self._stage_plan(0)
 
     def _stage_plan(self, li: int) -> int:
         entries = self._upload_plan.pop(li, None)
         if not entries:
             return 0
-        return sum(self.slot_caches[dev].prefetch([key])
-                   for dev, key in entries)
+        with TraceAnnotation("runtime.stage", layer=li, keys=len(entries)):
+            return sum(self.slot_caches[dev].prefetch([key])
+                       for dev, key in entries)
 
     def flush_pending(self) -> None:
         for li in sorted(self._upload_plan):
@@ -624,10 +652,10 @@ class ShardedSlotRuntime(SlotStreamRuntime):
         def build():
             from repro.models.moe import gather_slot_weights
 
-            def impl(bufs, row):
+            def slot_shard_gather(bufs, row):
                 self._count("slot_shard_gather")
                 return gather_slot_weights({}, bufs, row)
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_shard_gather)
         return self._fn("slot_shard_gather", build)
 
     def _gathered_weights(self, li: int):
@@ -664,8 +692,8 @@ class ShardedSlotRuntime(SlotStreamRuntime):
             jax, jnp = self._jax, self._jnp
             model, cfg, mesh, rep = self.model, self.cfg, self.mesh, self._rep
 
-            def impl(p, wts, perm, inv_perm, bc, x_mid, h2, gates, idx,
-                     active):
+            def slot_decode_post_sharded(p, wts, perm, inv_perm, bc, x_mid,
+                                         h2, gates, idx, active):
                 self._count(key)
 
                 def expert_fn(xg, _p):
@@ -683,7 +711,8 @@ class ShardedSlotRuntime(SlotStreamRuntime):
                     routing=(gates, idx), expert_fn=expert_fn)
                 counts = counts * active.astype(counts.dtype)[:, None]
                 return x_out, bc, counts
-            return self._jax.jit(impl, donate_argnums=(4,))
+            return self._jax.jit(slot_decode_post_sharded,
+                                 donate_argnums=(4,))
         return self._fn(key, build)
 
     def _prefill_post_sharded(self, desc, P):
@@ -694,8 +723,8 @@ class ShardedSlotRuntime(SlotStreamRuntime):
             jax, jnp = self._jax, self._jnp
             model, cfg, mesh, rep = self.model, self.cfg, self.mesh, self._rep
 
-            def impl(p, wts, perm, inv_perm, x_mid, h2, gates, idx,
-                     true_len):
+            def slot_prefill_post_sharded(p, wts, perm, inv_perm, x_mid,
+                                          h2, gates, idx, true_len):
                 self._count(key)
                 S = h2.shape[1]
                 token_mask = (jnp.arange(S)[None, :] < true_len[:, None])
@@ -713,7 +742,7 @@ class ShardedSlotRuntime(SlotStreamRuntime):
                     token_mask=token_mask, routing=(gates, idx),
                     expert_fn=expert_fn)
                 return x_out, aux["counts"]
-            return self._jax.jit(impl)
+            return self._jax.jit(slot_prefill_post_sharded)
         return self._fn(key, build)
 
     def _run_decode_post(self, desc, li, p, bc, x_mid, h2, gates, idx,
