@@ -6,8 +6,9 @@ and what it costs; this module is where expert weights actually move. A
 (and strips it out of the device param tree), and an :class:`ExpertSlotCache`
 owns a fixed-shape device buffer of ``n_slots ≪ L×E`` stacked expert triples
 (``w_gate/w_up/w_down`` per slot) plus the ``(L, E) → slot`` table the
-model's slot-indexed dispatch gathers through
-(:func:`repro.models.moe.gather_slot_weights`).
+model reads its expert rows through (:func:`repro.models.moe.moe_ffn`:
+the routed rows in decode, :func:`repro.models.moe.gather_slot_weights`
+in prefill).
 
 Wire tiers (DESIGN.md §7): the store quantizes each expert into the
 configured ``transfer_dtype`` (fp32/fp16/int8 + per-output-channel scales,

@@ -487,6 +487,14 @@ class Model:
                   for key, val in bc.items()}
         return x, h2, bc
 
+    @property
+    def decode_capacity_factor(self) -> float:
+        """MoE capacity factor of one-token decode: dropless (C >= T) by
+        default; serving deployments may trade exactness for 1/16th the
+        expert-slot padding (§Perf)."""
+        cfg = self.cfg
+        return cfg.decode_capacity_factor or cfg.moe.n_experts / cfg.moe.top_k
+
     def _decode_block_post(self, p, desc: LayerDesc, bc, x_mid, h2, *,
                            expert_fn=None, active=None, routing=None,
                            slot_weights=None, slot_ids=None):
@@ -498,11 +506,8 @@ class Model:
             y2, bc["cm"] = rwkv_lib.rwkv_channel_mix(p["rwkv"], cfg, h2,
                                                      bc["cm"])
         elif desc.is_moe:
-            # dropless (C >= T) by default; serving deployments may trade
-            # exactness for 1/16th the expert-slot padding (§Perf)
-            cf = (cfg.decode_capacity_factor
-                  or cfg.moe.n_experts / cfg.moe.top_k)
-            y2, moe_aux = moe_ffn(p["moe"], cfg, h2, capacity_factor=cf,
+            y2, moe_aux = moe_ffn(p["moe"], cfg, h2,
+                                  capacity_factor=self.decode_capacity_factor,
                                   expert_fn=expert_fn, routing=routing,
                                   slot_weights=slot_weights,
                                   slot_ids=slot_ids)

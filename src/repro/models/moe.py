@@ -1,11 +1,20 @@
 """Routed mixture-of-experts FFN.
 
-Dispatch is sort+gather based (GShard-style capacity bound, but without the
-O(T·E·C) one-hot dispatch tensors): tokens are flattened, their (token, expert)
-assignments sorted by expert, capacity-clipped, gathered into dense per-expert
-blocks ``(E, C, d)`` and processed by a grouped GEMM. Compute is therefore
-proportional to *active* experts (``T·k·d·f``), which keeps the roofline's
-MODEL_FLOPS / HLO_FLOPs ratio honest.
+Two global paths, picked at trace time from shapes and arguments alone
+(:func:`routed_rows`):
+
+* **Dispatch** (prefill, training, wide batches): sort+gather based
+  (GShard-style capacity bound, but without the O(T·E·C) one-hot dispatch
+  tensors): tokens are flattened, their (token, expert) assignments sorted
+  by expert, capacity-clipped, gathered into dense per-expert blocks
+  ``(E, C, d)`` and processed by a grouped GEMM. Compute is therefore
+  proportional to *active* experts (``T·k·d·f``), which keeps the
+  roofline's MODEL_FLOPS / HLO_FLOPs ratio honest; the weights read are all
+  ``E`` experts' (on the slot path, an ``E``-row gather of the slot
+  buffers).
+* **Routed rows** (decode: dropless, ``T·k < E``): each (token, k)
+  assignment gathers its one expert's weight row and applies it to its
+  token, so a step reads ``T·k`` expert rows instead of ``E``.
 
 Expert weights are stored stacked ``(E, d, f)`` so that (a) expert parallelism
 is one PartitionSpec on the leading axis and (b) the serving engine's offload
@@ -89,31 +98,49 @@ def route(p, m: MoEConfig, xf):
     return gates, idx, probs
 
 
-def gather_slot_weights(p, slot_weights, slot_ids):
+def _take(a, rows):
+    return jnp.take(a, rows, axis=0)
+
+
+def _take_rows(a, rows):
+    """``a[rows]`` for a short (static-length) index vector, as one dynamic
+    slice of the leading axis per row. XLA's TPU gather of a few rows first
+    slices the *whole* operand along its minor axis (its mini-gather), which
+    on a slot buffer copies every slot to read one."""
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(a, rows[i], 1)
+                            for i in range(rows.shape[0])])
+
+
+def gather_slot_weights(p, slot_weights, slot_ids, take=_take):
     """Substitute slot-cache expert weights into a MoE param dict.
 
     ``slot_weights``: stacked per-slot triples {w_up (n_slots, d, f),
     w_down (n_slots, f, d), w_gate? (n_slots, d, f)} — the device-resident
-    expert slot buffers. ``slot_ids``: (E,) int32 expert→slot table row for
-    this layer. Non-resident experts must be clamped to a valid slot by the
-    caller; their weights are garbage but harmless — an expert no real token
-    routes to contributes nothing to the output (zero gate / empty capacity
-    block), so only *activated* experts need live slots.
+    expert slot buffers. ``slot_ids``: the slots to take, in order — the
+    layer's whole (E,) expert→slot table row on the dispatch path (and for
+    the sharded runtime's per-device gathers), or the (T·k,) slots of the
+    routed assignments on the routed-row path. Non-resident experts must be
+    clamped to a valid slot by the caller; their weights are garbage but
+    harmless — an expert no real token routes to contributes nothing to the
+    output (zero gate / empty capacity block), so only *activated* experts
+    need live slots.
 
     Wire dtypes (DESIGN.md §7): when the slot cache streams fp16/int8, the
     buffers are narrow and int8 ships with ``<name>_scale`` fp32
-    per-output-channel rows. The gather stays in the wire dtype (cheap:
-    E rows, not n_slots) and dequantization happens here, in-jit on device
-    — ``q.astype(f32) * scale`` broadcast over the input axis — so compute
-    downstream is fp32 regardless of the wire. The fp32 wire path takes
-    the exact PR-5 gather (no cast, no scale): bit-identity preserved."""
+    per-output-channel rows. The gather stays in the wire dtype and
+    dequantization happens here, in-jit on device, on the gathered rows
+    only — ``q.astype(f32) * scale`` broadcast over the input axis — so
+    compute downstream is fp32 regardless of the wire. The fp32 wire path
+    takes the exact PR-5 gather (no cast, no scale): bit-identity
+    preserved. ``take(buffer, slot_ids)`` reads the rows: a gather, or
+    :func:`_take_rows` on the routed-row path."""
     p = dict(p)
     for name in ("w_gate", "w_up", "w_down"):
         if name in slot_weights:
-            w = jnp.take(slot_weights[name], slot_ids, axis=0)
+            w = take(slot_weights[name], slot_ids)
             sname = name + "_scale"
             if sname in slot_weights:
-                s = jnp.take(slot_weights[sname], slot_ids, axis=0)
+                s = take(slot_weights[sname], slot_ids)
                 w = w.astype(jnp.float32) * s[:, None, :]
             elif w.dtype == jnp.float16:
                 w = w.astype(jnp.float32)
@@ -142,15 +169,23 @@ def moe_ffn(p, cfg: ArchConfig, x, *, capacity_factor: float | None = None,
     the expert GEMM runs; aux_loss is 0 on this path (serving never uses it).
     ``slot_weights``/``slot_ids``: expert weights live in the device slot
     cache instead of ``p`` (see :func:`gather_slot_weights`).
+
+    When :func:`routed_rows` holds, the routed-row path runs instead of the
+    capacity dispatch (:func:`_moe_ffn_routed`): same math, nothing
+    dropped, ``T·k`` expert rows read instead of ``E``.
     """
+    B, S, d = x.shape
+    if routed_rows(cfg, B, S, capacity_factor,
+                   masked=token_mask is not None,
+                   custom_experts=expert_fn is not None):
+        return _moe_ffn_routed(p, cfg, x, routing, slot_weights, slot_ids)
     if slot_weights is not None:
         p = gather_slot_weights(p, slot_weights, slot_ids)
-    if cfg.moe_dispatch == "grouped" and x.shape[0] > 1:
+    if cfg.moe_dispatch == "grouped" and B > 1:
         return moe_ffn_grouped(p, cfg, x, capacity_factor=capacity_factor,
                                expert_fn=expert_fn, token_mask=token_mask,
                                routing=routing)
     m = cfg.moe
-    B, S, d = x.shape
     T = B * S
     xf = x.reshape(T, d)
     if routing is None:
@@ -204,7 +239,13 @@ def moe_ffn(p, cfg: ArchConfig, x, *, capacity_factor: float | None = None,
     if m.n_shared_experts:
         y = y + apply_ffn(p["shared"], x, cfg.act)
 
-    # --- aux: per-sequence expert counts (EAM row) + load-balance loss
+    return y, _routing_aux(m, idx, probs, B, S, token_mask)
+
+
+def _routing_aux(m: MoEConfig, idx, probs, B: int, S: int, token_mask=None):
+    """Per-sequence expert counts (B, E) — the EAM rows, pad tokens left
+    out — and the load-balance loss (0 when routing came precomputed)."""
+    E, k = m.n_experts, m.top_k
     one_hot = jax.nn.one_hot(idx.reshape(B, S * k), E, dtype=jnp.int32)
     if token_mask is not None:
         one_hot = one_hot * jnp.repeat(token_mask.astype(jnp.int32), k,
@@ -213,10 +254,74 @@ def moe_ffn(p, cfg: ArchConfig, x, *, capacity_factor: float | None = None,
     if probs is None:
         aux_loss = jnp.float32(0)
     else:
-        frac_tokens = counts.sum(axis=0).astype(jnp.float32) / (T * k)
+        frac_tokens = counts.sum(axis=0).astype(jnp.float32) / (B * S * k)
         frac_probs = probs.mean(axis=0)
         aux_loss = m.aux_loss_coef * E * jnp.sum(frac_tokens * frac_probs)
-    return y, {"counts": counts, "aux_loss": aux_loss}
+    return {"counts": counts, "aux_loss": aux_loss}
+
+
+def routed_rows(cfg: ArchConfig, B: int, S: int,
+                capacity_factor: float | None = None, *,
+                masked: bool = False, custom_experts: bool = False) -> bool:
+    """Whether :func:`moe_ffn` over x (B, S, d) takes the routed-row path.
+
+    Static (shapes and arguments only), so the path is fixed at trace time.
+    It needs: no ``expert_fn`` (``custom_experts``) and no ``token_mask``
+    (``masked``); a dropless capacity, C >= T (a token's k experts are
+    distinct, so no expert can then overflow and the dispatch would drop
+    nothing); and T·k < E, below which the routed rows read less than the
+    whole expert table. The grouped dispatch (B > 1) keeps its own path."""
+    m = cfg.moe
+    T = B * S
+    if cfg.moe_dispatch == "grouped" and B > 1:
+        return False
+    return (not masked and not custom_experts
+            and capacity(T, m, capacity_factor) >= T
+            and T * m.top_k < m.n_experts)
+
+
+def expert_rows(cfg: ArchConfig, B: int, S: int,
+                capacity_factor: float | None = None, *,
+                masked: bool = False, custom_experts: bool = False) -> int:
+    """Expert weight rows one :func:`moe_ffn` call over x (B, S, d) reads:
+    T·k on the routed-row path, E where it dispatches over the whole
+    table."""
+    if routed_rows(cfg, B, S, capacity_factor, masked=masked,
+                   custom_experts=custom_experts):
+        return B * S * cfg.moe.top_k
+    return cfg.moe.n_experts
+
+
+def _moe_ffn_routed(p, cfg: ArchConfig, x, routing, slot_weights, slot_ids):
+    """Routed-row MoE: each (token, k) assignment gathers its one expert's
+    weight row — ``idx`` into ``p``'s (E, …) stacks, or ``slot_ids[idx]``
+    into the slot buffers, dequantizing only those rows — and applies it to
+    its token: ``y_t = Σ_k gate_{t,k} · FFN_{idx_{t,k}}(x_t)``. The FFN is
+    :func:`grouped_expert_ffn` over (T·k, 1, d) blocks, so contraction order
+    and dtypes are the dispatch path's."""
+    m = cfg.moe
+    B, S, d = x.shape
+    T, k = B * S, m.top_k
+    xf = x.reshape(T, d)
+    if routing is None:
+        gates, idx, probs = route(p, m, xf)
+    else:
+        (gates, idx), probs = routing, None
+    flat_e = idx.reshape(T * k)
+    if slot_weights is not None:
+        w = gather_slot_weights({}, slot_weights, slot_ids[flat_e],
+                                take=_take_rows)
+    else:
+        w = {name: _take_rows(p[name], flat_e)
+             for name in ("w_gate", "w_up", "w_down") if name in p}
+    xg = jnp.repeat(xf, k, axis=0)[:, None]                  # (T·k, 1, d)
+    yg = grouped_expert_ffn(xg, w, cfg.act)[:, 0]            # (T·k, d)
+    contrib = yg * gates.reshape(T * k)[:, None].astype(yg.dtype)
+    y = contrib.reshape(T, k, d).sum(axis=1)
+    y = y.reshape(B, S, d).astype(x.dtype)
+    if m.n_shared_experts:
+        y = y + apply_ffn(p["shared"], x, cfg.act)
+    return y, _routing_aux(m, idx, probs, B, S)
 
 
 def moe_ffn_grouped(p, cfg: ArchConfig, x, *,
@@ -298,18 +403,7 @@ def moe_ffn_grouped(p, cfg: ArchConfig, x, *,
     if m.n_shared_experts:
         y = y + apply_ffn(p["shared"], x, cfg.act)
 
-    one_hot = jax.nn.one_hot(idx.reshape(B, S * k), E, dtype=jnp.int32)
-    if token_mask is not None:
-        one_hot = one_hot * jnp.repeat(token_mask.astype(jnp.int32), k,
-                                       axis=1)[..., None]
-    counts = one_hot.sum(axis=1)
-    if probs is None:
-        aux_loss = jnp.float32(0)
-    else:
-        frac_tokens = counts.sum(axis=0).astype(jnp.float32) / (B * S * k)
-        frac_probs = probs.mean(axis=0)
-        aux_loss = m.aux_loss_coef * E * jnp.sum(frac_tokens * frac_probs)
-    return y, {"counts": counts, "aux_loss": aux_loss}
+    return y, _routing_aux(m, idx, probs, B, S, token_mask)
 
 
 def grouped_expert_ffn(xg, p, act_name: str):

@@ -12,8 +12,11 @@ boundary:
     host        — read the routed expert ids, `ensure` them in the slot
                   cache (misses = timed demand uploads, victims = the
                   engine's Algorithm-2 verdict)
-    post (jit)  — capacity dispatch consuming *gathered per-slot weights*
-                  (`moe_ffn(routing=…, slot_weights=…, slot_ids=…)`)
+    post (jit)  — the expert FFN over the slot buffers
+                  (`moe_ffn(routing=…, slot_weights=…, slot_ids=…)`): in
+                  decode, the routed-row path gathers only the T·k routed
+                  experts' slot rows (`slot_ids[idx]`, on the device); in
+                  prefill, the capacity dispatch over all E gathered rows
 
 so only ONE layer's routed expert set must ever be resident at use time
 (the capacity floor is ``E``, not ``L×E``), and prefetch uploads issued at
@@ -51,8 +54,10 @@ Spans (``jax.profiler.TraceAnnotation``, free unless a profiler runs):
 ``runtime.decode`` / ``runtime.prefill`` around one layer walk, the host's
 reads of the router's top-k, ``post``'s counts and the token as
 ``runtime.read.route`` / ``.counts`` / ``.token`` (metadata ``layer``),
-``runtime.sync`` around the residency sync and ``runtime.stage`` around a
-planned layer's uploads.
+``runtime.post`` around each MoE ``post`` dispatch (metadata ``layer`` and
+``rows``, the expert weight rows that program reads), ``runtime.sync``
+around the residency sync and ``runtime.stage`` around a planned layer's
+uploads.
 """
 from __future__ import annotations
 
@@ -63,7 +68,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.slot_cache import ExpertSlotCache, HostExpertStore
 from repro.models.layers import embed_lookup
-from repro.models.moe import route
+from repro.models.moe import expert_rows, route
 from repro.serving.guard import bump_trace_count
 
 
@@ -191,6 +196,16 @@ class SlotStreamRuntime:
         self.slot_cache.ensure([(li, int(e)) for e in expert_ids],
                                self.victim_fn)
 
+    def _post_rows(self, prompt_len: Optional[int] = None) -> int:
+        """Expert weight rows one MoE ``post`` reads (the ``rows`` of its
+        ``runtime.post`` span), by the predicate that picks ``moe_ffn``'s
+        path: decode over the pool at the decode capacity, or the masked
+        prefill of one padded prompt of ``prompt_len``."""
+        if prompt_len is None:
+            return expert_rows(self.cfg, self.n_pool_slots, 1,
+                               self.model.decode_capacity_factor)
+        return expert_rows(self.cfg, 1, prompt_len, 2.0, masked=True)
+
     # -- decode --------------------------------------------------------------
     def _decode_embed(self):
         def build():
@@ -307,8 +322,10 @@ class SlotStreamRuntime:
                     used = (np.unique(idx_np[rows]) if rows.any()
                             else np.empty(0, np.int64))
                     self._ensure(li, used)
-                    x, bc, cnts = self._run_decode_post(
-                        desc, li, p, bc, x_mid, h2, gates, idx, active)
+                    with TraceAnnotation("runtime.post", layer=li,
+                                         rows=self._post_rows()):
+                        x, bc, cnts = self._run_decode_post(
+                            desc, li, p, bc, x_mid, h2, gates, idx, active)
                     # double-buffered overlap: issue the next MoE layer's
                     # planned uploads while this post computes
                     self._stage_plan(li + 1)
@@ -458,8 +475,10 @@ class SlotStreamRuntime:
                     with TraceAnnotation("runtime.read.route", layer=li):
                         idx_np = np.asarray(idx)[:true_len]   # real tokens
                     self._ensure(li, np.unique(idx_np))
-                    x, cnts = self._run_prefill_post(
-                        desc, P, li, p, x_mid, h2, gates, idx, tl)
+                    with TraceAnnotation("runtime.post", layer=li,
+                                         rows=self._post_rows(P)):
+                        x, cnts = self._run_prefill_post(
+                            desc, P, li, p, x_mid, h2, gates, idx, tl)
                     self._stage_plan(li + 1)
                     with TraceAnnotation("runtime.read.counts", layer=li):
                         counts_rows.append(np.asarray(cnts)[0])
@@ -646,6 +665,10 @@ class ShardedSlotRuntime(SlotStreamRuntime):
                               []).append((li, e))
         for dev, keys in groups.items():
             self.slot_caches[dev].ensure(keys, self.victim_fn)
+
+    def _post_rows(self, prompt_len: Optional[int] = None) -> int:
+        # every device gathers its cap rows for the all-to-all FFN
+        return self.placement.E
 
     # -- sharded expert weights ---------------------------------------------
     def _gather_fn(self):

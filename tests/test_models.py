@@ -181,3 +181,108 @@ def test_grouped_moe_dispatch_matches_oracle():
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                atol=2e-5, rtol=1e-4)
     assert int(aux["counts"].sum()) == 4 * 16 * cfg.moe.top_k
+
+
+# ---------------------------------------------------------------------------
+# Routed-row MoE path (decode: dropless, T·k < E)
+# ---------------------------------------------------------------------------
+
+def _moe_cfg(top_k=2, act="swiglu", n_shared=0, n_experts=None):
+    import dataclasses
+    cfg = get_config("qwen3-moe-235b-a22b").reduced()
+    m = dataclasses.replace(cfg.moe, top_k=top_k, n_shared_experts=n_shared,
+                            n_experts=n_experts or cfg.moe.n_experts)
+    return dataclasses.replace(cfg, act=act, moe=m)
+
+
+def _dispatches(cfg, x, p, **kw):
+    """Whether moe_ffn's traced program runs the capacity dispatch (its
+    argsort) rather than the routed rows."""
+    from repro.models.moe import moe_ffn
+    jaxpr = jax.make_jaxpr(lambda p, x: moe_ffn(p, cfg, x, **kw))(p, x)
+    return " sort[" in str(jaxpr)
+
+
+@pytest.mark.parametrize("n_shared", [0, 1], ids=["routed", "shared"])
+@pytest.mark.parametrize("act", ["swiglu", "gelu"])
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_routed_rows_match_dense_oracle(top_k, act, n_shared):
+    """At batch one the decode capacity takes the routed-row path, which
+    matches the dense-mask oracle, gated or plain, with or without a
+    shared expert."""
+    from repro.models.moe import route, routed_rows
+    cfg = _moe_cfg(top_k, act, n_shared)
+    E = cfg.moe.n_experts
+    p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    assert ("w_gate" in p) == (act == "swiglu")
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 1, cfg.d_model))
+    cf = E / top_k
+    assert routed_rows(cfg, 1, 1, cf)
+    assert not _dispatches(cfg, x, p, capacity_factor=cf)
+    y, aux = moe_ffn(p, cfg, x, capacity_factor=cf)
+    y_ref = moe_ffn_dense_oracle(p, cfg, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=2e-5, rtol=1e-4)
+    _, idx, _ = route(p, cfg.moe, x.reshape(1, -1))
+    want = np.zeros((1, E), np.int32)
+    want[0, np.asarray(idx)[0]] = 1
+    np.testing.assert_array_equal(np.asarray(aux["counts"]), want)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_routed_rows_match_dispatch_at_top1(dtype):
+    """Top-1 at batch one: the routed rows and the capacity dispatch (the
+    same expert FFN through the ``expert_fn`` seam, which keeps the
+    dispatch) pick the same expert and gate: identical counts and aux
+    loss, and outputs equal up to the GEMM's summation order. Not bit for
+    bit on the CPU: XLA emits the one-row GEMM as a fused vector-matrix
+    loop and the E-row one as a batched library dot (float32 differs in
+    the last bits, ~1e-6 by norm; bfloat16 mostly rounds them away)."""
+    from repro.models.moe import grouped_expert_ffn
+    cfg = _moe_cfg(top_k=1)
+    p = init_moe(jax.random.PRNGKey(0), cfg, jnp.dtype(dtype))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 1, cfg.d_model)
+                          ).astype(dtype)
+    cf = float(cfg.moe.n_experts)
+    y, aux = moe_ffn(p, cfg, x, capacity_factor=cf)
+    y_d, aux_d = moe_ffn(p, cfg, x, capacity_factor=cf,
+                         expert_fn=lambda xg, q: grouped_expert_ffn(
+                             xg, q, cfg.act))
+    assert y.dtype == y_d.dtype == jnp.dtype(dtype)
+    assert np.array_equal(np.asarray(aux["counts"]),
+                          np.asarray(aux_d["counts"]))
+    assert float(aux["aux_loss"]) == float(aux_d["aux_loss"])
+    a, b = (np.asarray(v, np.float64) for v in (y, y_d))
+    if dtype == "float32":
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
+    else:
+        # below half a bfloat16 ulp at unit scale, by norm
+        assert np.linalg.norm(a - b) <= 2 ** -9 * np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("case,B,S,kw,routed", [
+    ("decode", 1, 1, {"capacity_factor": 128.0}, True),
+    ("decode_pool", 8, 1, {"capacity_factor": 128.0}, True),
+    ("prefill_mask", 1, 16, {"capacity_factor": 2.0, "masked": True}, False),
+    ("lossy_factor", 8, 1, {"capacity_factor": 1.25}, False),
+    ("tk_at_least_e", 128, 1, {"capacity_factor": 128.0}, False),
+    ("expert_fn", 1, 1, {"capacity_factor": 128.0, "custom_experts": True},
+     False),
+])
+def test_routed_rows_predicate(case, B, S, kw, routed):
+    """The routed-row path needs a dropless capacity, T·k < E, no token
+    mask and no expert_fn; prefill, a lossy factor, wide batches and the
+    sharded seam keep the dispatch. ``expert_rows`` labels it, and the
+    traced program takes the path the predicate names."""
+    from repro.models.moe import expert_rows, grouped_expert_ffn, routed_rows
+    cfg = _moe_cfg(top_k=1, n_experts=128)
+    assert routed_rows(cfg, B, S, **kw) is routed
+    assert expert_rows(cfg, B, S, **kw) == (B * S if routed else 128)
+    p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jnp.zeros((B, S, cfg.d_model))
+    call = {"capacity_factor": kw["capacity_factor"]}
+    if kw.get("masked"):
+        call["token_mask"] = jnp.ones((B, S), bool)
+    if kw.get("custom_experts"):
+        call["expert_fn"] = lambda xg, q: grouped_expert_ffn(xg, q, cfg.act)
+    assert _dispatches(cfg, x, p, **call) is not routed

@@ -181,3 +181,15 @@ def test_fused_programs_are_named_by_their_keys(switch):
         *_abstract((srv.params, srv._cache)), jnp.zeros((1, P), jnp.int32),
         jnp.ones(1, jnp.int32), jnp.asarray(0, jnp.int32))
     assert pre.as_text().startswith("module @jit_prefill ")
+
+
+@pytest.mark.parametrize("walk", ["runtime.decode", "runtime.prefill"])
+def test_post_spans_carry_layer_and_rows(traced, walk):
+    """Every MoE ``post`` dispatch sits in a ``runtime.post`` span with its
+    layer and the expert rows it reads: a pool of four (T·k = 8 >= E) and
+    the masked prefill both dispatch over all E = 4 experts' rows."""
+    events = traced[1]
+    for w in (e for e in events if e[0] == walk):
+        posts = [e for e in _inside(events, w) if e[0] == "runtime.post"]
+        assert [e[3]["layer"] for e in posts] == list(range(N_MOE))
+        assert all(e[3]["rows"] == 4 for e in posts), posts

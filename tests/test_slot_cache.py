@@ -27,11 +27,11 @@ def model_and_params():
     return arch, model, params
 
 
-def _server(model_and_params, **kw):
+def _server(model_and_params, n_slots=4, **kw):
     arch, model, params = model_and_params
     cfg = EngineConfig(arch=arch, gpu_cache_experts=4, dram_cache_experts=8,
                        scheduler=SchedulerConfig(max_batch=4), **kw)
-    return JaxModelServer(cfg, model, params, n_slots=4, cache_len=64)
+    return JaxModelServer(cfg, model, params, n_slots=n_slots, cache_len=64)
 
 
 def _generate(srv, arch, n=3, new=6, seed=5):
@@ -181,3 +181,117 @@ def test_zero_recompiles_after_warmup_in_slot_mode(model_and_params):
     assert all(v == 1 for v in warm.values()), warm
     _generate(srv, arch, n=3, new=4, seed=4)
     assert srv.compile_counts == warm
+
+
+# ---------------------------------------------------------------------------
+# Routed rows: a pool of one decodes from the T·k routed slot rows alone
+# ---------------------------------------------------------------------------
+
+def test_pool_of_one_routed_rows_bit_identical(model_and_params):
+    """A pool of one (T·k = 2 < E = 4): decode ``post`` gathers only the
+    routed experts' slot rows, and at rf=0.5 gives tokens and EAMs
+    bit-equal to the fused step, which takes the routed-row path too."""
+    from repro.models.moe import routed_rows
+    arch, model, _ = model_and_params
+    assert routed_rows(arch, 1, 1, model.decode_capacity_factor)
+    out_ref, stats_ref = _generate(_server(model_and_params, n_slots=1),
+                                   arch)
+    srv = _server(model_and_params, n_slots=1, resident_fraction=0.5)
+    out, stats = _generate(srv, arch)
+    assert np.array_equal(out, out_ref)
+    for a, b in zip(stats["eams"], stats_ref["eams"]):
+        assert np.array_equal(a, b)
+    assert stats["demand_uploads"] > 0
+    assert srv.slot_runtime._post_rows() == arch.moe.top_k
+
+
+def _rel(a, b):
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+@pytest.mark.parametrize("dtype", ["fp16", "int8"])
+def test_routed_rows_dequantize_only_the_gathered_rows(model_and_params,
+                                                       dtype):
+    """Narrow wire at a pool of one: the routed-row path widens only the
+    T·k gathered slot rows (every int8→fp32 or fp16→fp32 convert of an
+    expert weight has T·k rows, none has the table's E or the buffer's
+    n_slots), with the scale rows gathered by the same slots, and matches
+    gather-everything-then-dispatch within the wire's bound."""
+    import jax.numpy as jnp
+    from repro.core.slot_cache import HostExpertStore, _moe_param_location
+    from repro.models.moe import (gather_slot_weights, grouped_expert_ffn,
+                                  moe_ffn, route)
+    arch, model, params = model_and_params
+    E, k = N_EXPERTS, arch.moe.top_k
+    store = HostExpertStore(model, params, transfer_dtype=dtype)
+    _, pos, g = _moe_param_location(model, model.moe_layers[0])
+    p_moe = jax.tree.map(lambda a: a[g], params["blocks"][pos])["moe"]
+    # six slots, expert e in slot perm[e]; the other two hold garbage
+    perm = np.array([4, 0, 5, 2])
+    imgs = [store.wire_expert(0, e) for e in range(E)]
+    slot_weights = {}
+    for name in imgs[0]:
+        buf = np.zeros((6,) + imgs[0][name].shape, imgs[0][name].dtype)
+        buf[perm] = np.stack([im[name] for im in imgs])
+        slot_weights[name] = jnp.asarray(buf)
+    slot_ids = jnp.asarray(perm, jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 1, arch.d_model),
+                          jnp.float32)
+    gates, idx, _ = route(p_moe, arch.moe, x.reshape(1, -1))
+    cf = model.decode_capacity_factor
+
+    def routed(sw, x):
+        return moe_ffn(p_moe, arch, x, capacity_factor=cf,
+                       routing=(gates, idx), slot_weights=sw,
+                       slot_ids=slot_ids)[0]
+    jaxpr = jax.make_jaxpr(routed)(slot_weights, x)
+    wire = jnp.int8 if dtype == "int8" else jnp.float16
+    widened = [eqn.outvars[0].aval.shape for eqn in jaxpr.eqns
+               if eqn.primitive.name == "convert_element_type"
+               and eqn.invars[0].aval.dtype == wire]
+    assert widened and all(s[0] == k for s in widened), widened
+    y = routed(slot_weights, x)
+    y_full, _ = moe_ffn(gather_slot_weights(p_moe, slot_weights, slot_ids),
+                        arch, x, capacity_factor=cf, routing=(gates, idx),
+                        expert_fn=lambda xg, q: grouped_expert_ffn(
+                            xg, q, arch.act))
+    y_ref, _ = moe_ffn(p_moe, arch, x, capacity_factor=cf,
+                       routing=(gates, idx))
+    tol = {"fp16": 1e-3, "int8": 1.5e-2}[dtype]   # test_quant_stream's
+    assert _rel(y, y_full) <= tol
+    assert _rel(y, y_ref) <= tol
+
+
+@pytest.mark.parametrize("n_slots", [1, 4])
+def test_decode_post_gathers_no_full_expert_table(model_and_params, n_slots):
+    """The lowered ``slot_decode_post`` of a pool of one has no gather whose
+    output leads with E (it takes the T·k routed slot rows); a pool of
+    four (T·k = 8 >= E) keeps the dispatch and its E-row gather."""
+    import re
+    arch, _, _ = model_and_params
+    srv = _server(model_and_params, n_slots=n_slots, resident_fraction=0.5)
+    rt = srv.slot_runtime
+    posts = {}
+    build_fn = rt._fn
+
+    def fn(key, builder):
+        f = build_fn(key, builder)
+        if not isinstance(key, tuple) or key[0] != "slot_decode_post":
+            return f
+
+        def call(*a):
+            posts.setdefault(key, (f, jax.tree.map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), a)))
+            return f(*a)
+        return call
+    rt._fn = fn
+    _generate(srv, arch, n=1, new=3)
+    assert posts
+    for f, args in posts.values():
+        text = f.lower(*args).as_text()
+        leads = [int(m) for m in re.findall(
+            r'"?stablehlo\.gather"?.*-> tensor<(\d+)x', text)]
+        assert leads
+        assert (N_EXPERTS in leads) is (n_slots > 1), leads
