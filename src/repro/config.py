@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Mapping, Optional, Tuple
 
 
 # ---------------------------------------------------------------------------
@@ -35,10 +35,11 @@ class MoEConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
+    """DeepSeek-V2 multi-head latent attention. ``q_lora_rank`` 0 or None:
+    no query compression (DeepSeek-V2-Lite), q is one projection of d."""
 
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -73,7 +74,25 @@ class AttnConfig:
     local_global_period: int = 0          # Gemma-2: alternate local/global every k
     rope_theta: float = 10000.0
     mrope_sections: Tuple[int, ...] = ()  # Qwen2-VL M-RoPE (t, h, w) half-dim split
-    mla: Optional[MLAConfig] = None
+    mla: Optional[MLAConfig] = None       # a mapping is taken as MLAConfig's fields
+    # YaRN rotary scaling as DeepSeek-V2 applies it (MLA only): rotary pairs
+    # blend from theta's frequency to it / yarn_factor between the
+    # correction range of beta_fast and beta_slow rotations over
+    # yarn_original_max_position; the softmax scale gains
+    # yarn_mscale(factor, yarn_mscale_all_dim)^2. yarn_factor 1 = plain RoPE.
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if isinstance(self.mla, Mapping):
+            object.__setattr__(self, "mla", MLAConfig(**self.mla))
+        if self.yarn_factor != 1.0 and self.mla is None:
+            raise ValueError("YaRN scaling is implemented for MLA only "
+                             "(DeepSeek-V2's convention)")
 
 
 # ---------------------------------------------------------------------------
@@ -210,9 +229,12 @@ class ArchConfig:
                 first_dense_layers=min(self.moe.first_dense_layers, 1),
             )
         if self.attn.mla is not None:
+            # query compression (and YaRN) stay as the source has them
             repl["attn"] = dataclasses.replace(
                 self.attn,
-                mla=MLAConfig(kv_lora_rank=64, q_lora_rank=96,
+                mla=MLAConfig(kv_lora_rank=64,
+                              q_lora_rank=96 if self.attn.mla.q_lora_rank
+                              else self.attn.mla.q_lora_rank,
                               qk_nope_head_dim=head_dim, qk_rope_head_dim=32,
                               v_head_dim=head_dim),
             )
@@ -243,7 +265,10 @@ def _attn_params(cfg: ArchConfig) -> int:
     if a.mla is not None:
         m = a.mla
         qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-        p = d * m.q_lora_rank + m.q_lora_rank * cfg.n_heads * qk      # q down/up
+        if m.q_lora_rank:
+            p = d * m.q_lora_rank + m.q_lora_rank * cfg.n_heads * qk  # q down/up
+        else:
+            p = d * cfg.n_heads * qk                                  # q
         p += d * (m.kv_lora_rank + m.qk_rope_head_dim)                # kv down + k_rope
         p += m.kv_lora_rank * cfg.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
         p += cfg.n_heads * m.v_head_dim * d                           # o proj

@@ -22,6 +22,7 @@ _MODULES = {
     "switch-base-256": "switch_base_256",
     "switch-large-128": "switch_large_128",
     "nllb-moe-128": "nllb_moe_128",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_MODULES)

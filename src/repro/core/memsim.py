@@ -109,11 +109,22 @@ class Link:
         entry = [-priority, next(self._counter), key, size, now, key]
         self._entries[key] = entry
         heapq.heappush(self._heap, entry)
+        self._compact()
 
     def cancel(self, key: Key) -> None:
         if key in self._entries:
             self._entries[key][-1] = None
             del self._entries[key]
+            self._compact()
+
+    def _compact(self) -> None:
+        """Drop invalidated entries once they outnumber the live ones, so a
+        long-running server's re-prioritised and cancelled requests do not
+        grow the heap without bound. Entries order by (priority, sequence),
+        which is unique, so the order of pops is unchanged."""
+        if len(self._heap) > 2 * len(self._entries) + 64:
+            self._heap = [e for e in self._heap if e[-1] is not None]
+            heapq.heapify(self._heap)
 
     def _pop(self) -> Optional[Tuple[Key, int, float, float]]:
         """-> (key, size, priority, available_at)"""
@@ -142,6 +153,7 @@ class Link:
         for e in self._entries.values():
             e[-1] = None
         self._entries.clear()
+        self._heap.clear()                         # every entry is dead now
 
 
 class MemSim:

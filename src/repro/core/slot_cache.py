@@ -237,11 +237,15 @@ class ExpertSlotCache:
     def _stage(self, key: Key) -> None:
         """Claim a free slot for ``key`` and start its host→device copies
         into the staging set (no mutation of ``bufs`` — the in-flight
-        kernels keep the weights they were dispatched with)."""
+        kernels keep the weights they were dispatched with). The copies'
+        issue is the ``slots.upload`` span (metadata ``layer``, ``bytes``)."""
         slot = self._free.pop()
-        w = self.store.wire_expert(*key)
-        self._staged[slot] = {name: self._jax.device_put(arr, self.device)
-                              for name, arr in w.items()}
+        with TraceAnnotation("slots.upload", layer=key[0],
+                             bytes=self.store.wire_expert_bytes):
+            w = self.store.wire_expert(*key)
+            self._staged[slot] = {
+                name: self._jax.device_put(arr, self.device)
+                for name, arr in w.items()}
         self.slot_of[key[0], key[1]] = slot
         self.key_of[slot] = key
         self.upload_bytes += self.store.wire_expert_bytes

@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ArchConfig
-from repro.models.layers import apply_rope, rms_norm_headwise, softcap
+from repro.models.layers import (apply_rope, rms_norm_headwise, softcap,
+                                 yarn_softmax_scale)
 
 NEG_INF = -2.0e38
 
@@ -29,10 +30,19 @@ def init_attn(rng, cfg: ArchConfig, dtype):
     if a.mla is not None:
         m = a.mla
         qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-        p = {
-            "w_dq": (jax.random.normal(ks[0], (d, m.q_lora_rank)) * std).astype(dtype),
-            "w_uq": (jax.random.normal(ks[1], (m.q_lora_rank, cfg.n_heads, qk))
-                     * m.q_lora_rank ** -0.5).astype(dtype),
+        if m.q_lora_rank:
+            p = {
+                "w_dq": (jax.random.normal(ks[0], (d, m.q_lora_rank))
+                         * std).astype(dtype),
+                "w_uq": (jax.random.normal(ks[1], (m.q_lora_rank, cfg.n_heads,
+                                                   qk))
+                         * m.q_lora_rank ** -0.5).astype(dtype),
+                "q_norm_scale": jnp.ones((m.q_lora_rank,), jnp.float32),
+            }
+        else:                          # no query compression: one projection
+            p = {"w_q": (jax.random.normal(ks[0], (d, cfg.n_heads, qk))
+                         * std).astype(dtype)}
+        p.update({
             "w_dkv": (jax.random.normal(ks[2], (d, m.kv_lora_rank)) * std).astype(dtype),
             "w_kr": (jax.random.normal(ks[3], (d, m.qk_rope_head_dim)) * std).astype(dtype),
             "w_uk": (jax.random.normal(ks[4], (m.kv_lora_rank, cfg.n_heads,
@@ -43,9 +53,8 @@ def init_attn(rng, cfg: ArchConfig, dtype):
                      * m.kv_lora_rank ** -0.5).astype(dtype),
             "w_o": (jax.random.normal(ks[6], (cfg.n_heads, m.v_head_dim, d))
                     * (cfg.n_heads * m.v_head_dim) ** -0.5).astype(dtype),
-            "q_norm_scale": jnp.ones((m.q_lora_rank,), jnp.float32),
             "kv_norm_scale": jnp.ones((m.kv_lora_rank,), jnp.float32),
-        }
+        })
         return p
     hd = cfg.head_dim_
     p = {
@@ -288,30 +297,38 @@ def attn_decode(p, cfg: ArchConfig, x, cache_k, cache_v, pos, *,
 
 def _mla_q(p, cfg, x, positions):
     m = cfg.attn.mla
-    cq = rms_norm_headwise(x @ p["w_dq"], p["q_norm_scale"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
+    if m.q_lora_rank:
+        cq = rms_norm_headwise(x @ p["w_dq"], p["q_norm_scale"])
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"])
     q_nope = q[..., : m.qk_nope_head_dim]
     q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions,
-                        cfg.attn.rope_theta)
+                        attn=cfg.attn)
     return q_nope, q_rope
 
 
 def _mla_latents(p, cfg, x, positions):
     c_kv = rms_norm_headwise(x @ p["w_dkv"], p["kv_norm_scale"])
     k_rope = apply_rope((x @ p["w_kr"])[..., None, :], positions,
-                        cfg.attn.rope_theta)[..., 0, :]
+                        attn=cfg.attn)[..., 0, :]
     return c_kv, k_rope
+
+
+def _mla_scale(cfg) -> float:
+    m = cfg.attn.mla
+    return ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+            * yarn_softmax_scale(cfg.attn))
 
 
 def mla_forward(p, cfg: ArchConfig, x, positions, mask=None):
     """Full-sequence MLA: latent KV is materialized per head (train/prefill).
     Returns (out, (c_kv, k_rope)) for cache seeding."""
-    m = cfg.attn.mla
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
     c_kv, k_rope = _mla_latents(p, cfg, x, positions)
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
     v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = _mla_scale(cfg)
     scores = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope)
               + jnp.einsum("bshk,btk->bhst", q_rope, k_rope)).astype(jnp.float32)
     if mask is None:
@@ -326,7 +343,6 @@ def mla_decode(p, cfg: ArchConfig, x, cache_ckv, cache_kr, pos):
     """Absorbed-matrix MLA decode: attention runs in the compressed latent
     space (the serving-efficient path from the DeepSeek-V2 paper). ``pos``
     may be a scalar or a (B,) per-slot position vector."""
-    m = cfg.attn.mla
     B = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
@@ -338,7 +354,7 @@ def mla_decode(p, cfg: ArchConfig, x, cache_ckv, cache_kr, pos):
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])
     scores = (jnp.einsum("bshr,btr->bhst", q_abs, cache_ckv)
               + jnp.einsum("bshk,btk->bhst", q_rope, cache_kr)).astype(jnp.float32)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = _mla_scale(cfg)
     mask = decode_mask(cache_ckv.shape[1], pos + 1)
     w = jax.nn.softmax(scores * scale + mask, axis=-1).astype(x.dtype)
     ctx = jnp.einsum("bhst,btr->bshr", w, cache_ckv)         # (B,1,H,r)

@@ -2,6 +2,8 @@
 M-RoPE, FFNs."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
@@ -99,20 +101,65 @@ def apply_ffn(p, x, act_name: str):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times (DeepSeek's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(attn, head_dim: int, theta: float):
+    """Rotary pairs [low, high] between which YaRN blends the original
+    frequency into the stretched one: the pairs that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context (DeepSeek's
+    ``yarn_find_correction_range``)."""
+    def pair(rotations):
+        return (head_dim * math.log(attn.yarn_original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(pair(attn.yarn_beta_fast))
+    high = math.ceil(pair(attn.yarn_beta_slow))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def rope_freqs(head_dim: int, theta: float = 10000.0, attn=None):
+    """Inverse rotary frequencies, one per pair. ``attn``, when given, sets
+    theta (its ``rope_theta``) in place of ``theta``. With YaRN (its
+    ``yarn_factor`` > 1) pairs below the correction range keep theirs,
+    pairs above it are divided by the factor, and a linear ramp joins
+    them."""
+    if attn is not None:
+        theta = attn.rope_theta
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if attn is None or attn.yarn_factor <= 1:
+        return inv
+    low, high = yarn_correction_range(attn, head_dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / attn.yarn_factor * ramp + inv * (1.0 - ramp)
+
+
+def yarn_softmax_scale(attn) -> float:
+    """Factor on the attention softmax scale under YaRN (1 without)."""
+    if not attn.yarn_mscale_all_dim:
+        return 1.0
+    return yarn_mscale(attn.yarn_factor, attn.yarn_mscale_all_dim) ** 2
 
 
 def apply_rope(x, positions, theta: float = 10000.0,
-               mrope_sections=()):
+               mrope_sections=(), attn=None):
     """Rotate ``x`` (..., S, H, hd) by ``positions``.
 
     ``positions``: (B, S) int32, or (3, B, S) for M-RoPE where the three planes
     are the temporal/height/width position ids (Qwen2-VL). ``mrope_sections``
-    splits the half-dim into per-plane sections.
+    splits the half-dim into per-plane sections. ``attn``: the AttnConfig
+    whose ``rope_theta`` replaces ``theta`` and whose YaRN fields stretch
+    the frequencies and scale cos and sin by mscale / mscale_all_dim
+    (:func:`rope_freqs`).
     """
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)  # (hd/2,)
+    inv = rope_freqs(hd, theta, attn)  # (hd/2,)
     if mrope_sections:
         assert positions.ndim == 3, "M-RoPE needs (3, B, S) position ids"
         secs = list(mrope_sections)
@@ -128,6 +175,11 @@ def apply_rope(x, positions, theta: float = 10000.0,
         ang = positions.astype(jnp.float32)[..., None] * inv   # (B, S, hd/2)
     cos = jnp.cos(ang)[..., None, :]                           # (B, S, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if attn is not None and attn.yarn_factor > 1:
+        f = (yarn_mscale(attn.yarn_factor, attn.yarn_mscale)
+             / yarn_mscale(attn.yarn_factor, attn.yarn_mscale_all_dim))
+        if f != 1.0:
+            cos, sin = cos * f, sin * f
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return y.astype(x.dtype)

@@ -22,6 +22,45 @@ def test_priority_order_and_resubmission():
     assert link._pop() is None
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_reprioritised_queue_stays_bounded_and_pops_in_order(seed):
+    """A server re-prioritises its prefetch queue every layer; the dead
+    entries this leaves must not pile up, and the pops must be those of a
+    plain (priority, submission order) sort of the live requests."""
+    import random
+    rng = random.Random(seed)
+    link = Link(10.0)
+    live = {}                                   # key -> (priority, order)
+    for step in range(5000):
+        key = rng.randrange(48)
+        op = rng.random()
+        if op < 0.85:
+            p = rng.random()
+            link.submit(key, p, 1)
+            live[key] = (p, step)
+        elif op < 0.97:
+            link.cancel(key)
+            live.pop(key, None)
+        else:
+            link.clear()
+            live.clear()
+        assert len(link._heap) <= 2 * link.queue_len() + 65
+    want = [k for k, _ in sorted(live.items(),
+                                 key=lambda kv: (-kv[1][0], kv[1][1]))]
+    got = []
+    while (nxt := link._pop()) is not None:
+        got.append(nxt[0])
+    assert got == want
+
+
+def test_clear_empties_the_heap():
+    link = Link(10.0)
+    for e in range(10):
+        link.submit(e, 0.1 * e, 1)
+    link.clear()
+    assert link._heap == [] and link._pop() is None
+
+
 def test_demand_fetch_from_dram_takes_transfer_time():
     sim = _sim()
     sim.in_dram.add(("l", 0))

@@ -286,3 +286,108 @@ def test_routed_rows_predicate(case, B, S, kw, routed):
     if kw.get("custom_experts"):
         call["expert_fn"] = lambda xg, q: grouped_expert_ffn(xg, q, cfg.act)
     assert _dispatches(cfg, x, p, **call) is not routed
+
+
+# -- DeepSeek-V2: YaRN RoPE and MLA without query compression -----------------
+
+
+def _hand_yarn(dim=64, theta=10000.0, factor=40.0, L0=4096, fast=32.0,
+               slow=1.0):
+    """DeepSeek's YaRN frequencies, written out from its formulas."""
+    import math
+
+    def corr(rot):
+        return dim * math.log(L0 / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr(fast)), 0)
+    high = min(math.ceil(corr(slow)), dim - 1)
+    out = []
+    for i in range(dim // 2):
+        base = theta ** (-2 * i / dim)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        out.append(base / factor * ramp + base * (1 - ramp))
+    return low, high, np.array(out)
+
+
+def test_yarn_matches_the_source_formulas():
+    """V2-Lite's rotary part (64 wide, 32 pairs): pairs 0-10 keep their
+    frequency, pairs 23-31 are divided by 40, a linear ramp joins them;
+    the softmax scale gains (1 + 0.1 * 0.707 * ln 40)^2 = 1.590 and cos /
+    sin keep mscale / mscale_all_dim = 1."""
+    from repro.models.layers import (rope_freqs, yarn_correction_range,
+                                     yarn_softmax_scale)
+    a = get_config("deepseek-v2-lite").attn
+    low, high, want = _hand_yarn()
+    assert yarn_correction_range(a, 64, a.rope_theta) == (low, high) \
+        == (10, 23)
+    got = np.asarray(rope_freqs(64, a.rope_theta, a))
+    plain = np.asarray(rope_freqs(64, a.rope_theta))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    np.testing.assert_allclose(got[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(got[23:], plain[23:] / 40, rtol=1e-6)
+    assert np.all(np.diff(plain / got) >= -1e-6)
+    assert yarn_softmax_scale(a) == pytest.approx(
+        (1 + 0.1 * 0.707 * np.log(40)) ** 2)
+    assert yarn_softmax_scale(a) == pytest.approx(1.5896, abs=1e-4)
+
+
+def test_yarn_factor_one_is_plain_rope():
+    """Factor 1 (and a config without YaRN) leaves RoPE as it was: the
+    frequencies, cos / sin and the softmax scale."""
+    import dataclasses
+
+    from repro.models.layers import apply_rope, rope_freqs, yarn_softmax_scale
+    a = dataclasses.replace(get_config("deepseek-v2-lite").attn,
+                            yarn_factor=1.0)
+    for hd in (32, 64, 128):
+        np.testing.assert_array_equal(np.asarray(rope_freqs(hd, 1e4, a)),
+                                      np.asarray(rope_freqs(hd, 1e4)))
+    assert yarn_softmax_scale(a) == 1.0
+    assert yarn_softmax_scale(get_config("qwen3-1.7b").attn) == 1.0
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 3, 64))
+    pos = jnp.broadcast_to(jnp.arange(5), (2, 5))
+    np.testing.assert_array_equal(np.asarray(apply_rope(x, pos, 1e4, attn=a)),
+                                  np.asarray(apply_rope(x, pos, 1e4)))
+
+
+def test_yarn_needs_mla_and_mla_takes_a_mapping():
+    from repro.config import AttnConfig, MLAConfig
+    with pytest.raises(ValueError, match="MLA"):
+        AttnConfig(yarn_factor=40.0)
+    a = AttnConfig(mla={"kv_lora_rank": 512, "q_lora_rank": None,
+                        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+                        "v_head_dim": 128}, yarn_factor=40.0)
+    assert a.mla == MLAConfig(kv_lora_rank=512, q_lora_rank=None)
+    assert a.mla == get_config("deepseek-v2-lite").attn.mla
+    hash(a)
+
+
+def test_decode_matches_forward_without_query_compression():
+    """Reduced DeepSeek-V2-Lite (no query compression, YaRN on, a dense
+    layer 0 then MoE with shared experts): prefill then serve_step equals
+    the full forward."""
+    full = get_config("deepseek-v2-lite")
+    cfg = full.reduced(n_layers=3)
+    assert not cfg.attn.mla.q_lora_rank and cfg.attn.yarn_factor == 40.0
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    attn = params["prefix"][0]["attn"]
+    assert "w_q" in attn and not {"w_dq", "w_uq", "q_norm_scale"} & set(attn)
+    assert attn["w_q"].shape == (cfg.d_model, cfg.n_heads,
+                                 cfg.attn.mla.qk_nope_head_dim
+                                 + cfg.attn.mla.qk_rope_head_dim)
+    K = 3
+    batch = _batch_for(cfg, jax.random.PRNGKey(1), seq=S + K)
+    full_logits, _ = model.forward(params, batch, capacity_factor=100.0)
+    cache = model.init_cache(B, S + K)
+    lg, cache, _ = model.prefill(params, {"tokens": batch["tokens"][:, :S]},
+                                 cache)
+    tol = 7e-4
+    assert float(jnp.abs(lg - full_logits[:, S - 1]).max()) < tol
+    for i in range(K):
+        lg, cache, _ = model.serve_step(params, cache,
+                                        batch["tokens"][:, S + i])
+        assert float(jnp.abs(lg - full_logits[:, S + i]).max()) < tol
+    # the parameter count knows the direct query projection
+    m = full.attn.mla
+    assert full.param_count() == pytest.approx(15.7e9, rel=0.01)
+    assert m.q_lora_rank is None

@@ -193,3 +193,19 @@ def test_post_spans_carry_layer_and_rows(traced, walk):
         posts = [e for e in _inside(events, w) if e[0] == "runtime.post"]
         assert [e[3]["layer"] for e in posts] == list(range(N_MOE))
         assert all(e[3]["rows"] == 4 for e in posts), posts
+
+
+def test_every_upload_has_a_span_with_layer_and_bytes(model_and_params,
+                                                      tmp_path):
+    """Each expert the slot cache stages for the host->device copy, on
+    demand or as a prefetch, sits in one ``slots.upload`` span carrying its
+    MoE layer and the bytes shipped: the spans add up to the counters."""
+    arch, _, _ = model_and_params
+    srv = _server(model_and_params, resident_fraction=0.5)
+    with jax.profiler.trace(str(tmp_path)):
+        srv.generate(_prompts(arch), max_new_tokens=5)
+    sc = srv.slot_runtime.slot_cache
+    ups = [e for e in _host_events(str(tmp_path)) if e[0] == "slots.upload"]
+    assert len(ups) == sc.demand_uploads + sc.prefetch_uploads > 0
+    assert sum(e[3]["bytes"] for e in ups) == sc.upload_bytes
+    assert {e[3]["layer"] for e in ups} <= set(range(N_MOE))

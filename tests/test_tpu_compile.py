@@ -123,3 +123,43 @@ def test_served_decode_post_compiles_for_v5e(one_chip):
     # copy of the slot buffers (a row gather compiles to slices of the
     # whole buffer, 0.8 GB here) or one layer's E gathered experts
     assert mem.temp_size_in_bytes < POOL * 2 * D * F * 2
+
+
+def test_served_deepseek_decode_post_compiles_for_v5e(one_chip):
+    """The same decode ``post`` for a DeepSeek-V2-Lite MoE layer at its
+    published widths (64 SwiGLU experts of 1408, top-6, beside 2 shared
+    experts applied as one SwiGLU of 2816) over 128 bf16 slot buffers: the
+    routed-row path with three-matrix experts and the shared experts."""
+    cfg = get_config("deepseek-v2-lite")
+    model = Model(cfg)
+    m, d, slots = cfg.moe, cfg.d_model, 128
+    desc = model.descs[model.moe_layers[0]]
+    shapes = model.init_shapes()["blocks"][0]
+    layer = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape[1:], a.dtype), shapes)
+    layer["moe"] = {k: v for k, v in layer["moe"].items()
+                    if k not in EXPERT_WEIGHT_NAMES}
+    assert set(layer["moe"]) == {"w_router", "shared"}
+    bufs = {"w_gate": _spec(one_chip, (slots, d, m.d_expert), jnp.bfloat16),
+            "w_up": _spec(one_chip, (slots, d, m.d_expert), jnp.bfloat16),
+            "w_down": _spec(one_chip, (slots, m.d_expert, d), jnp.bfloat16)}
+    bc = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: model._block_cache(desc, POOL, CACHE_LEN, 0)))
+    x = _spec(one_chip, (POOL, 1, d), jnp.bfloat16)
+    rt = SlotStreamRuntime.__new__(SlotStreamRuntime)
+    rt.model, rt.cfg, rt._jax, rt._jnp = model, cfg, jax, jnp
+    rt._fns, rt.compile_counts = {}, {}
+    post = rt._decode_post(desc)
+    compiled = post.lower(
+        layer, bufs, _spec(one_chip, (m.n_experts,), jnp.int32), bc, x, x,
+        _spec(one_chip, (POOL, m.top_k), jnp.float32),
+        _spec(one_chip, (POOL, m.top_k), jnp.int32),
+        _spec(one_chip, (POOL,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    # the POOL x top-6 routed rows of three matrices (17.3 MB each; 415 MB
+    # here, 416 MB compiled) and less than one more expert's worth beside
+    # them: never one layer's 64 gathered experts (1.1 GB) nor slices of
+    # the 128-slot buffers (2.2 GB)
+    row = 3 * d * m.d_expert * 2
+    assert mem.temp_size_in_bytes < (POOL * m.top_k + 1) * row
